@@ -133,7 +133,7 @@ def campaign_tiny(out_path: str = "BENCH_campaign.json"
     from repro.workloads.campaign import make_grid, run_campaign
 
     t0 = time.time()
-    art = run_campaign(make_grid("tiny"), workers=2, out_path=out_path,
+    art = run_campaign(make_grid("tiny"), workers=1, out_path=out_path,
                        grid_name="tiny")
     us = (time.time() - t0) * 1e6
     ov = art["reductions"]["overall"]

@@ -14,8 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced_config
-from repro.models import model as M
-from repro.runtime.serving_pool import ServingPool
+from repro.runtime.serving_pool import ServingPool, init_host_params
 from repro.serving.batching import ContinuousBatcher, Request
 
 
@@ -26,7 +25,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = reduced_config(ARCHS[args.arch])
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_host_params(cfg, seed=0)
     pool = ServingPool(cfg, params, capacity_tokens_per_replica=400.0)
     pool.scale_to(jax.devices()[:1])
     batcher = ContinuousBatcher(max_batch=8, bucket=64)

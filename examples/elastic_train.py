@@ -14,15 +14,13 @@ import argparse
 import sys
 import tempfile
 
-import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced_config
 from repro.configs.base import TrainConfig
-from repro.models import model as M
 from repro.runtime.elastic import ElasticTrainer
 from repro.runtime.orchestrator import PhoenixOrchestrator
-from repro.runtime.serving_pool import ServingPool
+from repro.runtime.serving_pool import ServingPool, init_host_params
 
 
 def main(argv=None):
@@ -36,7 +34,7 @@ def main(argv=None):
     trainer = ElasticTrainer(cfg, TrainConfig(learning_rate=1e-3),
                              global_batch=8, seq_len=32,
                              ckpt_dir=ckpt_dir, model_size=1)
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_host_params(cfg, seed=0)
     pool = ServingPool(cfg, params, capacity_tokens_per_replica=200.0)
     orch = PhoenixOrchestrator(trainer, pool, min_st_devices=2)
     orch.start()

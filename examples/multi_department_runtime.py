@@ -28,16 +28,14 @@ import argparse
 import sys
 import tempfile
 
-import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced_config
 from repro.configs.base import TrainConfig
 from repro.core.types import SLOConfig
-from repro.models import model as M
 from repro.runtime.elastic import ElasticTrainer
 from repro.runtime.orchestrator import MultiTenantOrchestrator
-from repro.runtime.serving_pool import ServingPool
+from repro.runtime.serving_pool import ServingPool, init_host_params
 from repro.serving.batching import ServiceTimeModel
 from repro.workloads.autoscaler import SLOAutoscaler
 
@@ -54,7 +52,7 @@ def main(argv=None):
     budget = args.budget if args.budget > 0 else None
 
     cfg = reduced_config(ARCHS[args.arch])
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_host_params(cfg, seed=0)
 
     def trainer():
         return ElasticTrainer(cfg, TrainConfig(learning_rate=1e-3),

@@ -36,27 +36,35 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                    # [c, dqk]
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)                    # [c, dv]
-    ig = i_ref[...].astype(jnp.float32)                 # [1, c] row vector
-    fg = f_ref[...].astype(jnp.float32)
+    ig = i_ref[0].astype(jnp.float32)                   # [1, c] row vector
+    fg = f_ref[0].astype(jnp.float32)
 
-    b = jnp.cumsum(fg, axis=-1)                         # [1, c]
-    btot = b[0, chunk - 1]
-    m_prev = m_ref[0, 0]
-    C = c_ref[...]
-    n = n_ref[...]                                      # [1? dqk]
-
-    # intra-chunk decay matrix: D[j,l] = b_j - b_l + i_l  (l <= j)
-    bj = b.reshape(chunk, 1)
-    bl = b.reshape(1, chunk)
-    il = ig.reshape(1, chunk)
-    logD = bj - bl + il
+    # Mosaic has no cumsum and no 1-D or scalar vectors: every quantity is
+    # a 2-D tile, prefix sums are a matmul with the causal mask, and a row
+    # vector becomes a column through a transposed broadcast.
     causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+
+    def col(row):                                       # [1, c] -> [c, 1]
+        return jnp.broadcast_to(row, (chunk, chunk)).T[:, :1]
+
+    b = jax.lax.dot_general(fg, causal.astype(jnp.float32),
+                            (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)   # [1, c]
+    btot = jnp.sum(fg, axis=1, keepdims=True)           # [1, 1]
+    m_prev = m_ref[...]                                 # [1, 1]
+    C = c_ref[...]
+    n = n_ref[...]                                      # [1, dqk]
+
+    # intra-chunk decay matrix: D[j,l] = b_j - b_l + i_l  (l <= j)
+    bl = jnp.broadcast_to(b, (chunk, chunk))
+    logD = bl.T - bl + jnp.broadcast_to(ig, (chunk, chunk))
     logD = jnp.where(causal, logD, NEG_INF)
-    m_intra = jnp.max(logD, axis=-1)                    # [c]
-    m_inter = b[0] + m_prev                             # [c]
+    m_intra = jnp.max(logD, axis=1, keepdims=True)      # [c, 1]
+    m_inter = col(b) + m_prev                           # [c, 1]
     m_j = jnp.maximum(m_intra, m_inter)
-    D = jnp.exp(logD - m_j[:, None])
+    D = jnp.exp(logD - m_j)
 
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -65,25 +73,29 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, o_ref,
                                   preferred_element_type=jnp.float32)
     n_intra = jax.lax.dot_general(w, k, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    dec_q = jnp.exp(m_inter - m_j)                      # [c]
+    dec_q = jnp.exp(m_inter - m_j)                      # [c, 1]
     h_inter = jax.lax.dot_general(q, C, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32) \
-        * dec_q[:, None]
-    n_inter = (q @ n.reshape(-1, 1))[:, 0] * dec_q      # [c]
+                                  preferred_element_type=jnp.float32) * dec_q
+    n_inter = jax.lax.dot_general(q, n, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32) * dec_q
     num = h_intra + h_inter
-    den = jnp.abs(jnp.sum(q * n_intra, axis=-1) + n_inter)
-    h = num / jnp.maximum(den, jnp.exp(-m_j))[:, None]
+    den = jnp.abs(jnp.sum(q * n_intra, axis=1, keepdims=True) + n_inter)
+    h = num / jnp.maximum(den, jnp.exp(-m_j))
     o_ref[0] = h.astype(o_ref.dtype)
 
     # ---- state update ----
-    m_state = jnp.maximum(btot + m_prev, jnp.max(btot - b[0] + ig[0]))
-    dec_k = jnp.exp(btot - b[0] + ig[0] - m_state)      # [c]
-    kd = k * dec_k[:, None]
-    c_ref[...] = C * jnp.exp(btot + m_prev - m_state) + jax.lax.dot_general(
+    g = btot - b + ig                                   # [1, c]
+    m_state = jnp.maximum(btot + m_prev, jnp.max(g, axis=1, keepdims=True))
+    kd = k * jnp.exp(col(g) - m_state)                  # [c, dqk]
+    # a [1, 1] tile broadcasts along one axis at a time: widen the exponent
+    # to a [1, dv] row before exp, so C's update broadcasts over sublanes only
+    decay = jnp.exp(btot + m_prev - m_state)            # [1, 1]
+    decay_row = jnp.exp(jnp.zeros((1, C.shape[1]), jnp.float32)
+                        + (btot + m_prev - m_state))    # [1, dv]
+    c_ref[...] = C * decay_row + jax.lax.dot_general(
         kd, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    n_ref[...] = n * jnp.exp(btot + m_prev - m_state) \
-        + jnp.sum(kd, axis=0).reshape(n.shape)
-    m_ref[0, 0] = m_state
+    n_ref[...] = n * decay + jnp.sum(kd, axis=0, keepdims=True)
+    m_ref[...] = m_state
 
 
 def mlstm_chunk_fwd(q, k, v, i_log, f_log, *, chunk: int = 128,
@@ -105,8 +117,8 @@ def mlstm_chunk_fwd(q, k, v, i_log, f_log, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, dqk), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, chunk, dqk), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, chunk, dv), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, chunk), lambda b, t: (b, t)),
-            pl.BlockSpec((1, chunk), lambda b, t: (b, t)),
+            pl.BlockSpec((1, 1, chunk), lambda b, t: (b, 0, t)),
+            pl.BlockSpec((1, 1, chunk), lambda b, t: (b, 0, t)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dv), lambda b, t: (b, t, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, dv), v.dtype),
@@ -116,4 +128,4 @@ def mlstm_chunk_fwd(q, k, v, i_log, f_log, *, chunk: int = 128,
             pltpu.VMEM((1, 1), jnp.float32),      # m
         ],
         interpret=interpret,
-    )(q, k, v, i_log, f_log)
+    )(q, k, v, i_log.reshape(BH, 1, S), f_log.reshape(BH, 1, S))

@@ -13,6 +13,7 @@ memory roofline term, which dominates recurrent layers at train/prefill.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,27 +21,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_s: int):
+def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_s: int,
+                  rows: int):
     is_ = pl.program_id(2)
 
     @pl.when(is_ == 0)
     def _init():
         carry_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    # all ref indices are Slices (pl.dslice), never bare ints: older JAX
-    # interpret-mode discharge rules reject scalar int indices
-    row = (pl.dslice(0, 1),)
+    # Mosaic loads and stores whole sublane tiles at dynamic offsets, so the
+    # loop steps over tiles of `rows` time rows and unrolls the recurrence
+    # inside each tile; each h_t is merged into the output tile by a select.
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
 
-    def step(t, h):
-        a_t = pl.load(a_ref, row + (pl.dslice(t, 1), slice(None)))[0, 0]
-        b_t = pl.load(b_ref, row + (pl.dslice(t, 1), slice(None)))[0, 0]
-        h = a_t.astype(jnp.float32) * h + b_t.astype(jnp.float32)   # [bw]
-        pl.store(o_ref, row + (pl.dslice(t, 1), slice(None)),
-                 h[None, None].astype(o_ref.dtype))
+    def tile(i, h):
+        r0 = pl.multiple_of(i * rows, rows)
+        a_t = a_ref[0, pl.ds(r0, rows), :].astype(jnp.float32)    # [rows, bw]
+        b_t = b_ref[0, pl.ds(r0, rows), :].astype(jnp.float32)
+        out = jnp.zeros_like(a_t)
+        for r in range(rows):
+            h = a_t[r:r + 1] * h + b_t[r:r + 1]                    # [1, bw]
+            out = jnp.where(row_id == r, h, out)
+        o_ref[0, pl.ds(r0, rows), :] = out.astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, carry_ref[...][0])
-    carry_ref[...] = h[None]
+    carry_ref[...] = jax.lax.fori_loop(0, block_s // rows, tile,
+                                       carry_ref[...])
 
 
 def rglru_scan_fwd(a, b, h0, *, block_s: int = 256, block_w: int = 512,
@@ -52,7 +58,10 @@ def rglru_scan_fwd(a, b, h0, *, block_s: int = 256, block_w: int = 512,
     assert S % block_s == 0 and W % block_w == 0, (S, W, block_s, block_w)
     grid = (B, W // block_w, S // block_s)
 
-    kernel = functools.partial(_rglru_kernel, block_s=block_s)
+    # one native sublane tile: 8 rows of 32-bit, 16 of 16-bit values
+    rows = math.gcd(block_s, 8 * 4 // min(a.dtype.itemsize, b.dtype.itemsize,
+                                          4))
+    kernel = functools.partial(_rglru_kernel, block_s=block_s, rows=rows)
     return pl.pallas_call(
         kernel,
         grid=grid,

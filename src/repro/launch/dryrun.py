@@ -193,8 +193,6 @@ def run_cell(arch: str, shape_name: str, mesh_tag: str, outdir: str) -> dict:
         }
         rec["fits_hbm"] = rec["memory"]["peak_bytes_est"] <= HW["hbm_bytes"]
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older JAX: list of per-computation dicts
-            ca = ca[0] if ca else {}
         rec["xla_cost"] = {k: float(v) for k, v in ca.items()
                            if k in ("flops", "bytes accessed",
                                     "transcendentals")}

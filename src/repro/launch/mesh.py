@@ -2,51 +2,26 @@
 
 Defined as functions (not module constants) so importing this module never
 touches jax device state.
-
-Newer JAX exposes explicit axis types (``jax.sharding.AxisType``) and an
-ambient-mesh setter (``jax.set_mesh``); older releases have neither. The
-helpers below feature-detect once so every call site works on both.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
 
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types kwarg for jax.make_mesh, or nothing on older JAX."""
-    if _HAS_AXIS_TYPES:
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
-def set_mesh(mesh):
-    """Context manager making `mesh` ambient: jax.set_mesh when available,
-    otherwise the legacy Mesh context manager (same scoping semantics)."""
-    if hasattr(jax, "set_mesh"):
-        cm = jax.set_mesh(mesh)
-        # jax.set_mesh is itself a context manager in current releases; be
-        # defensive in case a future version makes it a plain setter.
-        if hasattr(cm, "__enter__"):
-            return cm
-        return contextlib.nullcontext(mesh)
-    return mesh  # jax.sharding.Mesh is a context manager on older JAX
+def _auto_axes(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds a 2-pod outer axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / elastic runtime resizing)."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes), **_auto_axes(len(axes)))
 
 
 # TPU v5e-like hardware model (per chip) — values from the assignment.

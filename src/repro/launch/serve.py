@@ -3,6 +3,10 @@ synthetic (or World-Cup-like) request trace, with the paper's autoscaler.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
         --requests 64 --devices 4
+
+Without ``--reduced`` it serves the registered config at its published
+widths, e.g. ``--arch recurrentgemma-2b --prompt-len 128 --max-new 32``
+on one 16 GB chip.
 """
 import os
 import sys
@@ -24,10 +28,31 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 
+def serve_queue(pool, batcher, devices, log=print):
+    """Drain the batcher's queue through the pool, one generation round at
+    a time, rescaling replicas over ``devices`` before each round by the
+    paper's utilization rule. Returns (rounds, wall seconds); each round's
+    tokens are on the host when its generate call returns."""
+    t0 = time.perf_counter()
+    rounds = 0
+    while batcher.queue:
+        reqs = batcher.next_round()
+        offered = float(sum(len(r.prompt) + r.max_new
+                            for r in list(batcher.queue) + reqs))
+        pool.scale_to(devices[:max(
+            1, min(pool.desired_replicas(offered), len(devices)))])
+        batcher.run_round(reqs, pool.submit, now=time.perf_counter() - t0)
+        rounds += 1
+        log(f"round {rounds}: batch={len(reqs)} "
+            f"replicas={len(pool.replicas)} queued={len(batcher.queue)}")
+    return rounds, time.perf_counter() - t0
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="use the reduced same-family config (CPU-scale)")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
@@ -38,34 +63,22 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.configs import ARCHS, reduced_config
-    from repro.models import model as M
-    from repro.runtime.serving_pool import ServingPool
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.runtime.serving_pool import ServingPool, init_host_params
     from repro.serving.batching import ContinuousBatcher, Request
 
+    use_compile_cache()
     cfg = reduced_config(ARCHS[args.arch]) if args.reduced else ARCHS[args.arch]
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    pool = ServingPool(cfg, params, capacity_tokens_per_replica=args.capacity)
-    pool.scale_to(jax.devices()[:1])
+    pool = ServingPool(cfg, init_host_params(cfg, seed=0),
+                       capacity_tokens_per_replica=args.capacity)
     batcher = ContinuousBatcher(max_batch=args.max_batch)
     rng = np.random.default_rng(0)
     for i in range(args.requests):
         batcher.submit(Request(
             i, rng.integers(0, cfg.vocab_size, args.prompt_len,
                             dtype=np.int32), args.max_new))
-    t0 = time.time()
-    rounds = 0
-    while batcher.queue:
-        reqs = batcher.next_round()
-        offered = float(sum(len(r.prompt) + r.max_new
-                            for r in list(batcher.queue) + reqs))
-        pool.scale_to(jax.devices()[:max(
-            1, min(pool.desired_replicas(offered), len(jax.devices())))])
-        batcher.run_round(reqs, pool.submit, now=time.time() - t0)
-        rounds += 1
-        print(f"round {rounds}: batch={len(reqs)} "
-              f"replicas={len(pool.replicas)} queued={len(batcher.queue)}",
-              flush=True)
-    dt = time.time() - t0
+    _, dt = serve_queue(pool, batcher, jax.devices(),
+                        log=lambda m: print(m, flush=True))
     total_new = sum(r.max_new for r in batcher.completed)
     print(f"served {len(batcher.completed)} requests / {total_new} tokens "
           f"in {dt:.2f}s ({total_new / dt:.1f} tok/s)")

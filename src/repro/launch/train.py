@@ -51,8 +51,10 @@ def main(argv=None):
     from repro.configs import ARCHS, reduced_config
     from repro.configs.base import TrainConfig
     from repro.data.pipeline import SyntheticLM
+    from repro.launch.compile_cache import use_compile_cache
     from repro.runtime.elastic import ElasticTrainer
 
+    use_compile_cache()
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = reduced_config(cfg)

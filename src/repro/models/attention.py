@@ -7,9 +7,9 @@ keeps peak activation memory at O(S·c) instead of O(S²) — a 32k-token prefil
 would otherwise materialize a 128 GB logit tensor per device — while keeping
 HLO FLOPs *exactly* causal (we never visit kv chunks above the diagonal).
 
-On TPU the Pallas kernels in ``repro.kernels`` implement the same math; this
-module is the XLA path used by the CPU dry-run and as the oracle-level
-reference for integration tests.
+The models run this XLA path on every backend, the TPU included: nothing in
+``repro.models`` calls the Pallas kernels in ``repro.kernels``, which are
+tested against their own references and not yet wired in.
 """
 from __future__ import annotations
 
@@ -197,21 +197,19 @@ def attention_prefill(p, x, cfg: ModelConfig, positions, *, window: int = 0,
     max_len = max(max_len or S, S)
     L = min(window, max_len) if window else max_len
     keep = min(L, S)
+    # the kept positions S-keep..S-1 are contiguous, so their ring slots
+    # (pos % L) are one contiguous segment of 0..L-1, wrapping at most once:
+    # pad the segment to L slots (empty slots hold pos -1), then rotate it to
+    # start at slot (S-keep) % L. Pad + roll, not a scatter: GSPMD partitions
+    # rolls cleanly but replicates scattered caches, and the TPU compiler
+    # aborts on the scatter once it fuses into the prefill program.
+    pad = L - keep
+    shift = (S - keep) % L
     kv_pos = pos1d[S - keep:].astype(jnp.int32)
-    if keep == L:
-        # slots (pos % L) are a cyclic rotation of 0..L-1 — use roll, not
-        # scatter: GSPMD partitions rolls cleanly but replicates scattered
-        # caches ("involuntary full rematerialization"), a 20x collective
-        # regression on 32k prefills (EXPERIMENTS.md §Perf i1).
-        shift = int((S - L) % L) if L else 0
-        ck = jnp.roll(k[:, S - keep:], shift, axis=1)
-        cv = jnp.roll(v[:, S - keep:], shift, axis=1)
-        cpos = jnp.roll(kv_pos, shift, axis=0)
-        return y, {"k": ck, "v": cv, "pos": cpos}
-    slots = kv_pos % L
-    ck = jnp.zeros((B, L) + k.shape[2:], k.dtype).at[:, slots].set(k[:, S - keep:])
-    cv = jnp.zeros((B, L) + v.shape[2:], v.dtype).at[:, slots].set(v[:, S - keep:])
-    cpos = jnp.full((L,), -1, jnp.int32).at[slots].set(kv_pos)
+    widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+    ck = jnp.roll(jnp.pad(k[:, S - keep:], widths), shift, axis=1)
+    cv = jnp.roll(jnp.pad(v[:, S - keep:], widths), shift, axis=1)
+    cpos = jnp.roll(jnp.pad(kv_pos, (0, pad), constant_values=-1), shift)
     return y, {"k": ck, "v": cv, "pos": cpos}
 
 

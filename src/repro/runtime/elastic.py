@@ -27,7 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpointer as ckpt
 from repro.configs.base import ModelConfig, TrainConfig
-from repro.launch.mesh import set_mesh
 from repro.sharding import partitioning as pt
 from repro.training.optimizer import OptState
 from repro.training.train_step import TrainState, init_state, make_train_step
@@ -78,9 +77,11 @@ class ElasticTrainer:
                                        self.global_batch)
         restored = self._try_restore()
         if not restored:
-            with set_mesh(self.mesh):
-                state = init_state(jax.random.PRNGKey(self.seed), self.cfg)
-            self.state = jax.device_put(state, self._state_shardings())
+            # initialize straight into the sharded layout: no device ever
+            # holds the whole state (at published widths it outgrows a chip)
+            init = jax.jit(lambda k: init_state(k, self.cfg),
+                           out_shardings=self._state_shardings())
+            self.state = init(jax.random.PRNGKey(self.seed))
         self._compile()
 
     def resize(self, devices: Sequence):

@@ -274,6 +274,9 @@ class MultiTenantOrchestrator:
         elif want < have:
             give = have - want
             self.devs.reclaim(name, give)
+            # drop the replicas before the release re-grants their devices:
+            # a chip cannot hold a replica's weights and a trainer's state
+            dept.pool.scale_to(self.devs.groups[name])
             self.svc.release(name, give)
         dept.pool.scale_to(self.devs.groups[name])
         self.events.append({"kind": "scale", "dept": name,
@@ -418,6 +421,7 @@ class PhoenixOrchestrator:
         elif want < have:
             give = have - want
             self.devs.release_ws(give)
+            self.pool.scale_to(self.devs.ws)   # before the devices move on
             self.rps.ws_release(give)
         self.pool.scale_to(self.devs.ws)
         self.events.append({"kind": "ws_scale", "replicas":

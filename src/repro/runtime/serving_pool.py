@@ -18,26 +18,36 @@ from repro.configs.base import ModelConfig
 from repro.models import model as M
 
 
+def init_host_params(cfg: ModelConfig, seed: int = 0):
+    """A serving department's master copy of the weights, made from a seed
+    in host memory: replicas copy it to their own devices, so no chip holds
+    a copy that serves nothing."""
+    with jax.default_device(jax.devices("cpu")[0]):
+        return jax.jit(M.init_params, static_argnums=1)(
+            jax.random.PRNGKey(seed), cfg)
+
+
 class Replica:
     def __init__(self, cfg: ModelConfig, params_host, device):
         self.cfg = cfg
         self.device = device
+        # the programs run where their committed inputs live: the weights
+        # here, the prompt below, the cache and tokens they produce after
         self.params = jax.device_put(params_host, device)
         self.outstanding = 0
         self._decode = jax.jit(
-            lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg),
-            device=device)
+            lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg))
         self._prefill = jax.jit(
             lambda p, t, ml: M.prefill(p, t, cfg, max_len=ml),
-            static_argnums=(2,), device=device)
+            static_argnums=(2,))
 
     def generate(self, prompt: np.ndarray, max_new: int) -> np.ndarray:
         """prompt: [B, S] int32. Greedy decode max_new tokens."""
         self.outstanding += prompt.size + max_new
         try:
             B, S = prompt.shape
-            logits, cache = self._prefill(self.params, jnp.asarray(prompt),
-                                          S + max_new)
+            logits, cache = self._prefill(
+                self.params, jax.device_put(prompt, self.device), S + max_new)
             toks = [jnp.argmax(logits, axis=-1)]
             for i in range(max_new - 1):
                 nxt, cache = self._decode(self.params, cache,

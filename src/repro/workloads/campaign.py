@@ -29,7 +29,9 @@ wiring (the degenerate case); ``2hpc2ws`` consolidates 2 HPC + 2
 request-level WS departments; ``2hpc2ws1be`` adds a best-effort batch
 tenant. Cells are independent; ``--workers N`` fans them out over
 processes (fork), falling back to in-process execution if a pool cannot
-start.
+start. Workers run only where the queue flush runs on the CPU: an
+accelerator belongs to one process, so there the campaign refuses
+``--workers`` above 1.
 
 WS request queues (v6): cells run in chunks and each chunk's queues —
 every tenant's realized allocation, constant and piecewise capacity alike
@@ -658,6 +660,17 @@ def _throughput(rows: Sequence[Dict], executed: int, skipped: int,
 # ------------------------------------------------------------ execution
 
 
+def _flush_platform() -> str:
+    """The platform the batched queue flush runs on: the first entry of
+    JAX's platform setting when one is given (no backend start-up, so a
+    CPU-pinned parent forks clean), else JAX's default backend."""
+    import jax
+    platforms = jax.config.jax_platforms
+    if platforms:
+        return platforms.split(",")[0]
+    return jax.default_backend()
+
+
 def _run_cells_streaming(cells: Sequence[ScenarioCell], workers: int,
                          spool_path: Optional[str],
                          trace_dir: Optional[str] = None) -> List[Dict]:
@@ -676,6 +689,13 @@ def _run_cells_streaming(cells: Sequence[ScenarioCell], workers: int,
     chunks = [list(cells[i:i + QUEUE_CHUNK])
               for i in range(0, len(cells), QUEUE_CHUNK)]
     if workers > 1 and len(chunks) > 1:
+        platform = _flush_platform()
+        if platform != "cpu":
+            raise RuntimeError(
+                f"run_campaign(workers={workers}): the WS queue flush runs "
+                f"on {platform}, and an accelerator belongs to one process; "
+                "forked workers would each try to open it. Run the campaign "
+                "with workers=1.")
         try:
             from concurrent.futures import (ProcessPoolExecutor,
                                             as_completed)

@@ -30,8 +30,7 @@ tests/test_queueing_equivalence.py):
 heterogeneous cells through shape-bucketed ``jit(vmap(lax.scan))`` device
 programs — a Kiefer–Wolfowitz core for constant capacity and a k(t)-aware
 sorted-slot core for piecewise capacity — with the metric fold fused on
-device (float32 — golden-tolerance, not bit-identical), falling back to the
-exact numpy paths per cell when JAX is unavailable.
+device (float32 — golden-tolerance, not bit-identical).
 """
 from __future__ import annotations
 
@@ -56,6 +55,11 @@ SIM_COUNTERS: Dict[str, float] = {
     "no_wait": 0, "constant": 0, "event": 0, "reference": 0,
     "jax_batched": 0,
 }
+
+
+# batched queue jobs served per device platform ("tpu", "cpu", ...), the
+# record of where the device cores actually ran
+SERVED_ON: Dict[str, int] = {}
 
 
 def snapshot_counters() -> Dict[str, float]:
@@ -463,12 +467,9 @@ _JAX_CORES_MAX = 32          # LRU bound on compiled cores per process
 
 
 def _jax_modules():
-    try:
-        import jax
-        import jax.numpy as jnp
-        return jax, jnp
-    except Exception:                                    # pragma: no cover
-        return None
+    import jax
+    import jax.numpy as jnp
+    return jax, jnp
 
 
 def _cached_core(key: tuple, build):
@@ -558,10 +559,7 @@ def _kw_batched_core(n_pad: int, k_pad: int):
     [B, n_pad] traces, [B, k_pad] slot-free-time vectors (slots beyond a
     cell's k are pinned to inf), metric fold fused on device so the host
     transfer is one [B, len(FOLD_COLS)] block."""
-    mods = _jax_modules()
-    if mods is None:                                     # pragma: no cover
-        return None
-    jax, jnp = mods
+    jax, jnp = _jax_modules()
 
     def build():
         def one(t, s, free0, horizon, n_valid, slo_t):
@@ -614,10 +612,7 @@ def _pw_batched_core(n_pad: int, e_pad: int, k_pad: int):
     whose queue-adjusted arrival is still inside the horizon zeroes every
     slot finishing before the horizon (zeros keep the carry sorted).
     """
-    mods = _jax_modules()
-    if mods is None:                                     # pragma: no cover
-        return None
-    jax, jnp = mods
+    jax, jnp = _jax_modules()
     K = k_pad
 
     def build():
@@ -770,15 +765,15 @@ def simulate_queue_batch(jobs: Sequence[QueueJob], backend: str = "auto",
     the Kiefer–Wolfowitz core, piecewise-capacity cells on the k(t)-aware
     sorted-slot core — with the metric fold fused on device (float32:
     metrics agree with the exact paths to golden tolerance, not bitwise).
-    Falls back to the exact per-cell ``simulate_queue`` dispatch when JAX
-    is unavailable or ``backend='numpy'``. Results come back in input
-    order; ``stats_out``, when given, receives one impl tag per job
-    ("jax_batched" or "numpy")."""
+    ``backend='numpy'`` keeps the exact per-cell ``simulate_queue``
+    dispatch. Results come back in input order; ``stats_out``, when given,
+    receives one impl tag per job ("jax_batched" or "numpy"), and
+    ``SERVED_ON`` counts the device-served jobs per platform."""
     if backend not in ("auto", "jax", "numpy"):
         raise ValueError(f"unknown backend {backend!r}")
     out: List[Optional[QueueMetrics]] = [None] * len(jobs)
     tags = ["numpy"] * len(jobs)
-    use_jax = backend != "numpy" and _jax_modules() is not None
+    use_jax = backend != "numpy"
     buckets, caps = _plan(jobs) if use_jax else ({}, [None] * len(jobs))
     on_device = {i for rows in buckets.values() for i in rows}
     for i, job in enumerate(jobs):
@@ -840,6 +835,8 @@ def simulate_queue_batch(jobs: Sequence[QueueJob], backend: str = "auto",
                        jnp.asarray(ct_b), jnp.asarray(ck_b),
                        jnp.asarray(hi_b), jnp.asarray(hz),
                        jnp.asarray(nv), jnp.asarray(st))
+        for dev in res.devices():
+            SERVED_ON[dev.platform] = SERVED_ON.get(dev.platform, 0) + B
         res = np.asarray(res, dtype=np.float64)          # [B, FOLD_COLS]
         for r, i in enumerate(rows):
             out[i] = _metrics_from_fold(len(jobs[i].trace), res[r],

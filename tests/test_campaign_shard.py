@@ -261,3 +261,31 @@ def test_reduce_metrics_order_independent():
     fwd = reduce_metrics(list(rows))
     rev = reduce_metrics(list(reversed(rows)))
     assert fwd == rev
+
+
+# ------------------------------------------------------ one chip owner
+
+
+def test_flush_platform_reads_the_pinned_platform():
+    import jax
+    from repro.workloads.campaign import _flush_platform
+    assert _flush_platform() == jax.default_backend() == "cpu"
+
+
+def test_forked_workers_refused_when_the_flush_runs_on_an_accelerator(
+        monkeypatch):
+    """An accelerator belongs to one process: with the queue flush on a
+    TPU, workers > 1 is an error before any cell runs, and workers=1
+    still runs."""
+    import repro.workloads.campaign as campaign
+    cells = [dataclasses.replace(FAST_CELLS[0], seed=s)
+             for s in range(campaign.QUEUE_CHUNK + 1)]
+    ran = []
+    monkeypatch.setattr(campaign, "_flush_platform", lambda: "tpu")
+    monkeypatch.setattr(campaign, "run_cell_chunk",
+                        lambda ch, trace_dir=None: ran.append(ch) or [])
+    with pytest.raises(RuntimeError, match="workers=1"):
+        run_campaign(cells, workers=2)
+    assert ran == []
+    run_campaign(cells, workers=1)
+    assert sum(len(ch) for ch in ran) == len(cells)

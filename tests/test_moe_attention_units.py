@@ -162,3 +162,31 @@ def test_moe_expert_parallel_same_math_on_single_device():
     y1, _ = moe_forward(p, x, cfg, num_groups=2)
     y2, _ = moe_forward(p, x, cfg_ep, num_groups=2)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-6)
+
+
+@pytest.mark.parametrize("S,window,max_len", [
+    (8, 0, 12),      # global cache longer than the prompt
+    (8, 0, 8),       # global cache exactly full
+    (8, 16, 12),     # window wider than the cache: plain prefix
+    (8, 4, 12),      # ring of 4 wrapped twice
+    (8, 6, 12),      # ring of 6, prompt wraps mid-ring
+    (5, 8, 12),      # ring not yet full
+])
+def test_prefill_cache_matches_stepwise_decode_cache(S, window, max_len):
+    """Prefill's cache puts position p at ring slot p % L, exactly where
+    attention_decode would have written it one token at a time."""
+    from repro.models import attention as attn
+    cfg = reduced_config(ARCHS["deepseek-7b"])
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    p = attn.init_attention(k1, cfg, jnp.float32)
+    x = jax.random.normal(k2, (2, S, cfg.d_model), jnp.float32)
+    _, cache = attn.attention_prefill(p, x, cfg, jnp.arange(S),
+                                      window=window, max_len=max_len)
+    step = attn.init_kv_cache(cfg, 2, max_len, window=window,
+                              dtype=jnp.float32)
+    for t in range(S):
+        _, step = attn.attention_decode(p, x[:, t:t + 1], step, cfg, t,
+                                        window=window)
+    np.testing.assert_array_equal(cache["pos"], step["pos"])
+    np.testing.assert_allclose(cache["k"], step["k"], atol=1e-5)
+    np.testing.assert_allclose(cache["v"], step["v"], atol=1e-5)

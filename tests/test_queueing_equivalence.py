@@ -270,16 +270,31 @@ def test_batched_mixed_const_and_piecewise_buckets():
 
 
 def test_batched_counter_attribution():
+    import jax
+    from repro.workloads.queueing import SERVED_ON
     jobs = _pw_jobs(7, n_cells=3)
     before = snapshot_counters()
+    served0 = dict(SERVED_ON)
     tags: list = []
     simulate_queue_batch(jobs, stats_out=tags)
     d = counters_delta(before)
     assert d["calls"] == 3 and d["requests"] == sum(len(j.trace)
                                                     for j in jobs)
-    if tags.count("jax_batched") == 3:
-        assert d["jax_batched"] == 3
-    assert "jax_batched" in SIM_COUNTERS
+    assert tags == ["jax_batched"] * 3 and d["jax_batched"] == 3
+    platform = jax.default_backend()
+    assert SERVED_ON[platform] - served0.get(platform, 0) == 3
+
+
+def test_batched_path_does_not_swallow_jax_errors(monkeypatch):
+    """No silent numpy fallback: a failure to reach JAX surfaces."""
+    from repro.workloads import queueing
+
+    def broken():
+        raise ImportError("jax is part of the installation")
+
+    monkeypatch.setattr(queueing, "_jax_modules", broken)
+    with pytest.raises(ImportError):
+        simulate_queue_batch(_pw_jobs(3, n_cells=2))
 
 
 # ------------------------------------------------- bucket plan regression

@@ -573,6 +573,53 @@ def test_multitenant_orchestrator_routes_counts_to_devices():
     orch.svc.check()
 
 
+class _ExclusiveTrainer(_StubTrainer):
+    """Fails if it is given a device on which a serving replica still sits:
+    a chip cannot hold both a replica's weights and a trainer's state."""
+
+    def __init__(self, pools, **kw):
+        super().__init__(**kw)
+        self.pools = pools
+
+    def _own(self, devices):
+        shared = {d for p in self.pools for d in p.replicas} & set(devices)
+        assert not shared, shared
+
+    def start(self, devices):
+        self._own(devices)
+        super().start(devices)
+
+    def resize(self, devices):
+        self._own(devices)
+        super().resize(devices)
+
+
+@pytest.mark.parametrize("wiring", ["multitenant", "phoenix"])
+def test_released_replicas_leave_before_the_trainer_arrives(wiring):
+    from repro.runtime.orchestrator import (MultiTenantOrchestrator,
+                                            PhoenixOrchestrator)
+    devices = [f"dev{i}" for i in range(4)]
+    pool = _StubPool()
+    tr = _ExclusiveTrainer([pool], model_size=1, global_batch=12)
+    if wiring == "multitenant":
+        orch = MultiTenantOrchestrator(devices=devices, policy="slo_headroom")
+        orch.add_latency("serve", pool, priority=0, floor=1)
+        orch.add_batch("train", tr, priority=1, min_devices=2)
+        orch.latency_tick("serve", 1.0)
+        orch.start()
+        tick = lambda n: orch.latency_tick("serve", float(n))  # noqa: E731
+    else:
+        orch = PhoenixOrchestrator(tr, pool, devices=devices,
+                                   min_st_devices=2)
+        orch.start()
+        tick = lambda n: orch.ws_tick(float(n))  # noqa: E731
+    for want in (2, 1, 2, 0):
+        tick(want)
+        assert len(pool.replicas) == want
+        assert len(tr.devices) + want <= 4
+    assert tr.resizes >= 3
+
+
 def test_multitenant_orchestrator_feeds_latency_signals_to_engine():
     """The runtime twin of the simulator's signal path: measured serving
     latency becomes TenantSignals headroom, and the slo_headroom engine
