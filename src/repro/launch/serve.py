@@ -6,7 +6,10 @@ synthetic (or World-Cup-like) request trace, with the paper's autoscaler.
 
 Without ``--reduced`` it serves the registered config at its published
 widths, e.g. ``--arch recurrentgemma-2b --prompt-len 128 --max-new 32``
-on one 16 GB chip.
+on one 16 GB chip. ``--profile DIR`` records the run in a profiler trace
+under ``DIR``: the serving path's ``serve.*`` spans (``repro.serving.spans``)
+on the device trace's clock, for TensorBoard's profile plugin or for
+Perfetto (``perfetto_trace.json.gz``).
 """
 import os
 import sys
@@ -22,17 +25,21 @@ def _early_args(argv):
 _early_args(sys.argv)
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import time  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+
+from repro.serving import spans  # noqa: E402
 
 
 def serve_queue(pool, batcher, devices, log=print):
     """Drain the batcher's queue through the pool, one generation round at
     a time, rescaling replicas over ``devices`` before each round by the
     paper's utilization rule. Returns (rounds, wall seconds); each round's
-    tokens are on the host when its generate call returns."""
+    tokens are on the host when its generate call returns. Each round's log
+    line gives the process's mean queue wait so far, from the counters."""
     t0 = time.perf_counter()
     rounds = 0
     while batcher.queue:
@@ -43,8 +50,11 @@ def serve_queue(pool, batcher, devices, log=print):
             1, min(pool.desired_replicas(offered), len(devices)))])
         batcher.run_round(reqs, pool.submit, now=time.perf_counter() - t0)
         rounds += 1
+        c = spans.snapshot()
+        wait = c["serve.queue_wait_s"] / c["serve.requests_batched"]
         log(f"round {rounds}: batch={len(reqs)} "
-            f"replicas={len(pool.replicas)} queued={len(batcher.queue)}")
+            f"replicas={len(pool.replicas)} queued={len(batcher.queue)} "
+            f"mean queue wait {wait:.3f} s")
     return rounds, time.perf_counter() - t0
 
 
@@ -60,6 +70,9 @@ def main(argv=None):
     ap.add_argument("--capacity", type=float, default=400.0,
                     help="tokens/interval one replica absorbs at 100%% util")
     ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--profile", metavar="DIR",
+                    help="record the serving run in a profiler trace "
+                         "under DIR")
     args = ap.parse_args(argv)
 
     from repro.configs import ARCHS, reduced_config
@@ -77,8 +90,10 @@ def main(argv=None):
         batcher.submit(Request(
             i, rng.integers(0, cfg.vocab_size, args.prompt_len,
                             dtype=np.int32), args.max_new))
-    _, dt = serve_queue(pool, batcher, jax.devices(),
-                        log=lambda m: print(m, flush=True))
+    with (jax.profiler.trace(args.profile, create_perfetto_trace=True)
+          if args.profile else contextlib.nullcontext()):
+        _, dt = serve_queue(pool, batcher, jax.devices(),
+                            log=lambda m: print(m, flush=True))
     total_new = sum(r.max_new for r in batcher.completed)
     print(f"served {len(batcher.completed)} requests / {total_new} tokens "
           f"in {dt:.2f}s ({total_new / dt:.1f} tok/s)")

@@ -7,8 +7,7 @@ outstanding tokens (the paper's LVS least-connection policy); the §III-C
 """
 from __future__ import annotations
 
-import time
-from typing import Dict, List, Optional, Sequence
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +15,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
+from repro.serving import spans
 
 
 def init_host_params(cfg: ModelConfig, seed: int = 0):
@@ -35,26 +35,40 @@ class Replica:
         # here, the prompt below, the cache and tokens they produce after
         self.params = jax.device_put(params_host, device)
         self.outstanding = 0
-        self._decode = jax.jit(
-            lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg))
-        self._prefill = jax.jit(
-            lambda p, t, ml: M.prefill(p, t, cfg, max_len=ml),
-            static_argnums=(2,))
+
+        # named, so that the device trace reads jit_serve_prefill(<id>)
+        # and jit_serve_decode(<id>) for the two programs
+        def serve_prefill(p, t, ml):
+            return M.prefill(p, t, cfg, max_len=ml)
+
+        def serve_decode(p, c, t, pos):
+            return M.decode_step(p, c, t, pos, cfg)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill, static_argnums=(2,))
 
     def generate(self, prompt: np.ndarray, max_new: int) -> np.ndarray:
         """prompt: [B, S] int32. Greedy decode max_new tokens."""
         self.outstanding += prompt.size + max_new
         try:
             B, S = prompt.shape
-            logits, cache = self._prefill(
-                self.params, jax.device_put(prompt, self.device), S + max_new)
-            toks = [jnp.argmax(logits, axis=-1)]
-            for i in range(max_new - 1):
-                nxt, cache = self._decode(self.params, cache,
-                                          toks[-1][:, None],
-                                          jnp.int32(S + i))
-                toks.append(jnp.argmax(nxt, axis=-1))
-            return np.stack([np.asarray(t) for t in toks], axis=1)
+            with spans.span("serve.prefill"):
+                logits, cache = self._prefill(
+                    self.params, jax.device_put(prompt, self.device),
+                    S + max_new)
+                toks = [jnp.argmax(logits, axis=-1)]
+            spans.add("serve.prefills")
+            spans.add("serve.prompt_tokens", B * S)
+            with spans.span("serve.decode"):
+                for i in range(max_new - 1):
+                    nxt, cache = self._decode(self.params, cache,
+                                              toks[-1][:, None],
+                                              jnp.int32(S + i))
+                    toks.append(jnp.argmax(nxt, axis=-1))
+            spans.add("serve.decode_steps", max_new - 1)
+            spans.add("serve.decode_rows", B * (max_new - 1))
+            with spans.span("serve.fetch"):
+                return np.stack([np.asarray(t) for t in toks], axis=1)
         finally:
             self.outstanding -= prompt.size + max_new
 
@@ -68,7 +82,6 @@ class ServingPool:
         self.params_host = params_host
         self.capacity = capacity_tokens_per_replica
         self.replicas: List[Replica] = []
-        self.inflight_tokens = 0.0
 
     # -------------------------------------------------------------- scaling
     def scale_to(self, devices: Sequence):

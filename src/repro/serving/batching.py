@@ -8,11 +8,13 @@ is the WS CMS's unit of work — the pool's replicas each run one of these.
 from __future__ import annotations
 
 import dataclasses
-import heapq
+import time
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Deque, List, Optional
 
 import numpy as np
+
+from repro.serving import spans
 
 
 @dataclasses.dataclass
@@ -23,6 +25,8 @@ class Request:
     arrival: float = 0.0
     done: Optional[np.ndarray] = None
     finish_time: float = 0.0
+    queued_at: float = float("nan")     # perf_counter at submit
+    batched_at: float = float("nan")    # perf_counter at next_round
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +86,9 @@ class ContinuousBatcher:
         self.completed: List[Request] = []
 
     def submit(self, req: Request):
+        req.queued_at = time.perf_counter()
         self.queue.append(req)
+        spans.add("serve.requests_queued")
 
     def next_round(self) -> Optional[List[Request]]:
         """Pick up to max_batch requests with compatible shapes."""
@@ -100,6 +106,13 @@ class ContinuousBatcher:
             else:
                 rest.append(r)
         self.queue.extendleft(reversed(rest))
+        now = time.perf_counter()
+        for r in round_reqs:
+            r.batched_at = now
+        spans.add("serve.rounds")
+        spans.add("serve.requests_batched", len(round_reqs))
+        spans.add("serve.queue_wait_s",
+                  sum(now - r.queued_at for r in round_reqs))
         return round_reqs
 
     def estimate_round_time(self, reqs: List[Request],
@@ -119,12 +132,18 @@ class ContinuousBatcher:
                 + max_new * slow / model.decode_tokens_per_s)
 
     def run_round(self, reqs: List[Request], generate_fn, now: float = 0.0):
-        """generate_fn(prompts [B, S], max_new) -> [B, max_new]."""
-        S = max(len(r.prompt) for r in reqs)
-        prompts = np.stack([np.pad(r.prompt, (S - len(r.prompt), 0))
-                            for r in reqs])
-        max_new = max(r.max_new for r in reqs)
-        out = generate_fn(prompts.astype(np.int32), max_new)
+        """generate_fn(prompts [B, S], max_new) -> [B, max_new]. The
+        ``serve.round`` span carries the number ``next_round`` gave it."""
+        with spans.span("serve.round",
+                        round=int(spans.snapshot()["serve.rounds"]),
+                        batch=len(reqs),
+                        requests=" ".join(str(r.req_id) for r in reqs)):
+            with spans.span("serve.pack"):
+                S = max(len(r.prompt) for r in reqs)
+                prompts = np.stack([np.pad(r.prompt, (S - len(r.prompt), 0))
+                                    for r in reqs]).astype(np.int32)
+            max_new = max(r.max_new for r in reqs)
+            out = generate_fn(prompts, max_new)
         for i, r in enumerate(reqs):
             r.done = out[i, :r.max_new]
             r.finish_time = now
