@@ -187,7 +187,7 @@ def attention_prefill(p, x, cfg: ModelConfig, positions, *, window: int = 0,
 
     The cache is allocated at ``max_len`` (>= S) slots so subsequent decode
     steps can append; sliding-window layers use a ring buffer whose slot for
-    position p is ``p % L`` — consistent with ``attention_decode``.
+    position p is ``p % L`` — consistent with ``write_kv_rows``.
     """
     B, S, _ = x.shape
     pos1d = positions if positions.ndim == 1 else positions[0]
@@ -215,32 +215,51 @@ def attention_prefill(p, x, cfg: ModelConfig, positions, *, window: int = 0,
 
 def attention_decode(p, x, cache, cfg: ModelConfig, cur_pos, *, window: int = 0,
                      theta: float = 10_000.0):
-    """One-token decode. x: [B, 1, D]; cur_pos: scalar int (current position).
+    """One-token decode that reads ``cache`` and does not write it.
 
-    Returns ([B,1,D], new_cache). Ring-buffer update for window layers.
+    x: [B, 1, D]; cur_pos: scalar int (current position). The token attends
+    to the cache's slots other than its own ring slot (which it is about to
+    overwrite) and to its own k/v, under one softmax: the same positions as
+    attending to the cache with the token written in. Returns ([B,1,D],
+    rows), rows = {"k", "v": [B,1,K,hd], "pos": [1]}, the token's entries
+    for ``write_kv_rows``.
     """
     B = x.shape[0]
     pos_b = jnp.full((B, 1), cur_pos, jnp.int32)
     q, k, v = _project_qkv(p, x, cfg, pos_b, theta)   # q [B,1,H,hd]
     L = cache["k"].shape[1]
-    # ring slot; for global caches cur_pos < L always, so this is identity.
     slot = (jnp.asarray(cur_pos) % L).astype(jnp.int32)
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
-    cpos = jax.lax.dynamic_update_slice_in_dim(
-        cache["pos"], jnp.full((1,), cur_pos, jnp.int32), slot, axis=0)
 
     K, G = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
     hd = cfg.head_dim
     qg = q.reshape(B, 1, K, G, hd)
     scale = 1.0 / math.sqrt(hd)
-    logits = jnp.einsum("bqkgh,bskh->bkgqs", qg, ck).astype(jnp.float32) * scale
-    valid = cpos >= 0
+    old = jnp.einsum("bqkgh,bskh->bkgqs", qg, cache["k"]).astype(
+        jnp.float32) * scale
+    cpos = cache["pos"]
+    valid = (cpos >= 0) & (jnp.arange(L) != slot)
     if window:
         valid = valid & (cpos > cur_pos - window)
-    logits = jnp.where(valid[None, None, None, None, :], logits, NEG_INF)
-    w = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bkgqs,bskh->bkgqh", w.astype(cv.dtype), cv)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, 1, cfg.q_dim)
+    old = jnp.where(valid[None, None, None, None, :], old, NEG_INF)
+    own = jnp.einsum("bqkgh,bqkh->bkgq", qg, k).astype(jnp.float32) * scale
+    w = jax.nn.softmax(jnp.concatenate([old, own[..., None]], axis=-1),
+                       axis=-1)
+    o = jnp.einsum("bkgqs,bskh->bkgqh", w[..., :L].astype(v.dtype),
+                   cache["v"], preferred_element_type=jnp.float32)
+    o = o + w[..., L:] * v.transpose(0, 2, 1, 3)[:, :, None].astype(
+        jnp.float32)
+    o = o.astype(v.dtype).transpose(0, 3, 1, 2, 4).reshape(B, 1, cfg.q_dim)
     y = dense(p["wo"], o)
-    return y, {"k": ck, "v": cv, "pos": cpos}
+    return y, {"k": k, "v": v, "pos": jnp.full((1,), cur_pos, jnp.int32)}
+
+
+def write_kv_rows(cache, rows, cur_pos, *, lead: int = 0):
+    """Write one step's ``rows`` into ``cache`` at ring slot cur_pos % L
+    (the position itself for global caches, where cur_pos < L). ``lead``
+    stacked axes precede each leaf's own (1 for the scanned layers)."""
+    L = cache["pos"].shape[-1]
+    slot = (jnp.asarray(cur_pos) % L).astype(jnp.int32)
+    put = jax.lax.dynamic_update_slice_in_dim
+    return {"k": put(cache["k"], rows["k"], slot, axis=lead + 1),
+            "v": put(cache["v"], rows["v"], slot, axis=lead + 1),
+            "pos": put(cache["pos"], rows["pos"], slot, axis=lead)}

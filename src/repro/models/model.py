@@ -82,7 +82,12 @@ def apply_block(kind: str, p, x, cfg: ModelConfig, *, mode: str,
                 positions=None, cache=None, cur_pos=None,
                 constrain: Constrain = _ident, moe_groups: int = 1,
                 max_len: int = 0):
-    """Returns (x, aux, new_cache)."""
+    """Returns (x, aux, new_cache).
+
+    In decode mode an attention block leaves its cache as it is and returns,
+    in place of a new cache, the token's rows for ``attn.write_kv_rows``;
+    recurrent blocks return their whole (small) new state.
+    """
     act = activation(cfg.act)
     aux: Dict[str, jnp.ndarray] = {}
     new_cache = None
@@ -316,6 +321,15 @@ def prefill(params, batch_in, cfg: ModelConfig, *, constrain: Constrain = _ident
     return logits[:, 0], cache
 
 
+def _write_decode(kind: str, cache, update, cur_pos, *, lead: int = 0):
+    """A block's cache after one decode step: an attention block's rows
+    written into its cache at the step's slot, a recurrent block's state
+    as returned."""
+    if kind in ("attn", "local"):
+        return attn.write_kv_rows(cache, update, cur_pos, lead=lead)
+    return update
+
+
 def decode_step(params, cache, tokens, cur_pos, cfg: ModelConfig, *,
                 constrain: Constrain = _ident, moe_groups: int = 1):
     """One decode step.
@@ -323,6 +337,11 @@ def decode_step(params, cache, tokens, cur_pos, cfg: ModelConfig, *,
     tokens: [B, 1] token ids (or [B, 1, D] embeddings for embedding-input
     archs); cur_pos: scalar int32 (current position, uniform across batch).
     Returns (logits [B, V...], new_cache).
+
+    The layer scan only reads the KV caches and emits each attention
+    layer's new rows; one write per leaf after the scan puts them in
+    place. Under a jit that donates ``cache`` the step thus reads the cache
+    once and writes one position, with no copy of it.
     """
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     x = _embed_in(params, tokens, cfg, compute_dtype)
@@ -331,24 +350,27 @@ def decode_step(params, cache, tokens, cur_pos, cfg: ModelConfig, *,
 
     def rep_body(xc, inp):
         rep_params, rep_cache = inp
-        new_caches = {}
+        updates = {}
         for j, kind in enumerate(pattern):
-            xc, _, c = apply_block(kind, rep_params[f"b{j}"], xc, cfg,
+            xc, _, u = apply_block(kind, rep_params[f"b{j}"], xc, cfg,
                                    mode="decode", cache=rep_cache[f"b{j}"],
                                    cur_pos=cur_pos, constrain=constrain,
                                    moe_groups=moe_groups)
-            new_caches[f"b{j}"] = c
-        return xc, new_caches
+            updates[f"b{j}"] = u
+        return xc, updates
 
-    x, rep_caches = jax.lax.scan(rep_body, x,
-                                 (params["repeats"], cache["repeats"]))
-    new_cache = {"repeats": rep_caches, "tail": {}}
+    x, rep_updates = jax.lax.scan(rep_body, x,
+                                  (params["repeats"], cache["repeats"]))
+    new_cache = {"repeats": {
+        f"b{j}": _write_decode(kind, cache["repeats"][f"b{j}"],
+                               rep_updates[f"b{j}"], cur_pos, lead=1)
+        for j, kind in enumerate(pattern)}, "tail": {}}
     for j, kind in enumerate(tail):
-        x, _, c = apply_block(kind, params["tail"][f"t{j}"], x, cfg,
-                              mode="decode", cache=cache["tail"][f"t{j}"],
-                              cur_pos=cur_pos, constrain=constrain,
-                              moe_groups=moe_groups)
-        new_cache["tail"][f"t{j}"] = c
+        c = cache["tail"][f"t{j}"]
+        x, _, u = apply_block(kind, params["tail"][f"t{j}"], x, cfg,
+                              mode="decode", cache=c, cur_pos=cur_pos,
+                              constrain=constrain, moe_groups=moe_groups)
+        new_cache["tail"][f"t{j}"] = _write_decode(kind, c, u, cur_pos)
     x = apply_norm(params["final_norm"], x)
     logits = _head_out(params, x, cfg)
     return logits[:, 0], new_cache

@@ -27,6 +27,23 @@ def init_host_params(cfg: ModelConfig, seed: int = 0):
             jax.random.PRNGKey(seed), cfg)
 
 
+def serve_programs(cfg: ModelConfig):
+    """A replica's two device programs: ``prefill(params, tokens[B,S],
+    max_len)`` and ``decode(params, cache, tokens[B,1], pos)``. The decode
+    step takes the cache as donated and updates it in place; a caller
+    rebinds the cache it returns and uses the old one no more."""
+    # named, so that the device trace reads jit_serve_prefill(<id>)
+    # and jit_serve_decode(<id>) for the two programs
+    def serve_prefill(p, t, ml):
+        return M.prefill(p, t, cfg, max_len=ml)
+
+    def serve_decode(p, c, t, pos):
+        return M.decode_step(p, c, t, pos, cfg)
+
+    return (jax.jit(serve_prefill, static_argnums=(2,)),
+            jax.jit(serve_decode, donate_argnums=(1,)))
+
+
 class Replica:
     def __init__(self, cfg: ModelConfig, params_host, device):
         self.cfg = cfg
@@ -35,17 +52,7 @@ class Replica:
         # here, the prompt below, the cache and tokens they produce after
         self.params = jax.device_put(params_host, device)
         self.outstanding = 0
-
-        # named, so that the device trace reads jit_serve_prefill(<id>)
-        # and jit_serve_decode(<id>) for the two programs
-        def serve_prefill(p, t, ml):
-            return M.prefill(p, t, cfg, max_len=ml)
-
-        def serve_decode(p, c, t, pos):
-            return M.decode_step(p, c, t, pos, cfg)
-
-        self._decode = jax.jit(serve_decode)
-        self._prefill = jax.jit(serve_prefill, static_argnums=(2,))
+        self._prefill, self._decode = serve_programs(cfg)
 
     def generate(self, prompt: np.ndarray, max_new: int) -> np.ndarray:
         """prompt: [B, S] int32. Greedy decode max_new tokens."""

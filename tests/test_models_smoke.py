@@ -84,6 +84,28 @@ def test_prefill_decode_matches_forward(name, rng):
         assert float(jnp.max(jnp.abs(ld - logits_full[:, t]))) < 2e-3, t
 
 
+@pytest.mark.parametrize("name,layers", [("gemma3-12b", 8),
+                                         ("recurrentgemma-2b", 7)])
+def test_decode_past_the_window_matches_forward(name, layers, rng):
+    """Decoding twice around a "local" layer's ring buffer (window 16),
+    from a prompt that already wrapped it, matches the full-sequence
+    forward. gemma3 at 8 layers puts two local layers in the unscanned
+    tail; recurrentgemma at 7 stacks two scanned repeats beside a
+    recurrent tail."""
+    cfg = reduced_config(ARCHS[name]).with_(num_layers=layers)
+    assert cfg.window_size == 16
+    params = M.init_params(rng, cfg)
+    B, S, P = 2, 52, 20
+    full = _inputs(cfg, rng, B, S)
+    logits_full, _ = M.forward(params, full, cfg)
+    lp, cache = M.prefill(params, full[:, :P], cfg, max_len=S)
+    assert float(jnp.max(jnp.abs(lp - logits_full[:, P - 1]))) < 2e-3
+    step = jax.jit(lambda p, c, t, i: M.decode_step(p, c, t, i, cfg))
+    for t in range(P, S):
+        ld, cache = step(params, cache, full[:, t:t + 1], jnp.int32(t))
+        assert float(jnp.max(jnp.abs(ld - logits_full[:, t]))) < 2e-3, t
+
+
 @pytest.mark.parametrize("name", ARCH_NAMES)
 def test_param_count_analytic_close(name, rng):
     """Analytic param_count (used for MODEL_FLOPS) tracks actual leaves."""

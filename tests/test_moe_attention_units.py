@@ -174,7 +174,7 @@ def test_moe_expert_parallel_same_math_on_single_device():
 ])
 def test_prefill_cache_matches_stepwise_decode_cache(S, window, max_len):
     """Prefill's cache puts position p at ring slot p % L, exactly where
-    attention_decode would have written it one token at a time."""
+    decoding would have written it one token at a time."""
     from repro.models import attention as attn
     cfg = reduced_config(ARCHS["deepseek-7b"])
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
@@ -185,8 +185,9 @@ def test_prefill_cache_matches_stepwise_decode_cache(S, window, max_len):
     step = attn.init_kv_cache(cfg, 2, max_len, window=window,
                               dtype=jnp.float32)
     for t in range(S):
-        _, step = attn.attention_decode(p, x[:, t:t + 1], step, cfg, t,
+        _, rows = attn.attention_decode(p, x[:, t:t + 1], step, cfg, t,
                                         window=window)
+        step = attn.write_kv_rows(step, rows, t)
     np.testing.assert_array_equal(cache["pos"], step["pos"])
     np.testing.assert_allclose(cache["k"], step["k"], atol=1e-5)
     np.testing.assert_allclose(cache["v"], step["v"], atol=1e-5)

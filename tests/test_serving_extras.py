@@ -1,4 +1,6 @@
-"""Sampler + continuous batcher + data pipeline unit tests."""
+"""Sampler + continuous batcher + replica + data pipeline unit tests."""
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,3 +102,26 @@ def test_data_pipeline_host_sharding_partitions_batch():
     # different hosts draw different rows
     assert not np.array_equal(np.asarray(shards[0]["tokens"]),
                               np.asarray(shards[1]["tokens"]))
+
+
+def test_replica_decode_takes_the_prefill_cache_in_place():
+    """One ``generate`` hands the prefill's cache to the donated decode
+    step: its buffers are consumed, and JAX finds every one usable."""
+    from repro.configs import ARCHS, reduced_config
+    from repro.runtime.serving_pool import Replica, init_host_params
+    cfg = reduced_config(ARCHS["gemma3-12b"])
+    replica = Replica(cfg, init_host_params(cfg), jax.devices()[0])
+    caches, prefill = [], replica._prefill
+
+    def kept(*args):
+        out = prefill(*args)
+        caches.append(out[1])
+        return out
+    replica._prefill = kept
+    prompt = np.arange(2 * 12, dtype=np.int32).reshape(2, 12) % cfg.vocab_size
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers")
+        out = replica.generate(prompt, 8)
+    assert out.shape == (2, 8)
+    (cache,) = caches
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))

@@ -9,6 +9,7 @@ import, so that under several test workers only the worker given this file
 loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,59 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
         .lower(params, cache, tok, pos).compile()
     used = _fits(compiled)
     assert used > 5 * 2**30          # the whole 2.7B-parameter model is there
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?%?(\S+)\s*=\s*(.*?)\s([\w-]+)\(")
+
+
+def _slab_copies(hlo: str, slab: str):
+    """Instructions of ``hlo`` whose result holds a ``slab``-shaped array
+    and that copy it: ``copy``, ``copy-start`` or a ``copy*`` fusion."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None or slab not in m.group(2):
+            continue
+        name, op = m.group(1), m.group(3)
+        if op in ("copy", "copy-start") or (op == "fusion"
+                                            and name.startswith("copy")):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("max_len", [640, 1088])
+def test_served_decode_step_updates_its_cache_in_place(one_chip, max_len):
+    """The replica's decode program at the deepseek-7b serving cell's
+    widths (15 layers, 32 heads of 128, batch 12, the chat and document
+    caches): the donated cache is aliased to the one returned, the step
+    needs no cache-sized scratch, and no op copies a layer's cache slab.
+    The same step undonated must show such a copy, so that the search
+    is seen to find one."""
+    from repro.configs import ARCHS
+    from repro.models import model as M
+    from repro.runtime.serving_pool import serve_programs
+    cfg = ARCHS["deepseek-7b"].with_(num_layers=15)
+    B = 12
+    params = _on(jax.eval_shape(lambda k: M.init_params(k, cfg),
+                                jax.random.PRNGKey(0)), one_chip)
+    cache = _on(M.init_cache(cfg, B, max_len, abstract=True), one_chip)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _, decode = serve_programs(cfg)
+    compiled = decode.lower(params, cache, tok, pos).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    # all of it aliased; the chip pads the [layers, L] position table to
+    # its (8, 128) tiles, a few KiB over the cache's own bytes
+    assert 0 <= mem.alias_size_in_bytes - cache_bytes < 1e-3 * cache_bytes
+    assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    slab = f",{max_len},{cfg.num_kv_heads},{cfg.head_dim}]"
+    assert _slab_copies(compiled.as_text(), slab) == []
+    _fits(compiled)
+    undonated = jax.jit(lambda p, c, t, i: M.decode_step(p, c, t, i, cfg)) \
+        .lower(params, cache, tok, pos).compile()
+    assert _slab_copies(undonated.as_text(), slab)
 
 
 # ------------------------------------------------------------------ kernels
