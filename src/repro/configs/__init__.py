@@ -1,9 +1,11 @@
 """Architecture registry: one module per assigned architecture."""
 from __future__ import annotations
 
+import dataclasses
+
 from .base import (ALL_SHAPES, DECODE_32K, LONG_500K, PREFILL_32K, SHAPES_BY_NAME,
-                   TRAIN_4K, ModelConfig, MoEConfig, ShapeConfig, TrainConfig,
-                   shapes_for)
+                   TRAIN_4K, MLAConfig, ModelConfig, MoEConfig, ShapeConfig,
+                   TrainConfig, YarnScaling, shapes_for)
 
 from .recurrentgemma_2b import CONFIG as recurrentgemma_2b
 from .deepseek_7b import CONFIG as deepseek_7b
@@ -15,6 +17,7 @@ from .qwen3_moe_30b_a3b import CONFIG as qwen3_moe_30b_a3b
 from .dbrx_132b import CONFIG as dbrx_132b
 from .musicgen_large import CONFIG as musicgen_large
 from .xlstm_1_3b import CONFIG as xlstm_1_3b
+from .deepseek_v2_lite import CONFIG as deepseek_v2_lite
 
 ARCHS = {
     c.name: c
@@ -29,6 +32,7 @@ ARCHS = {
         dbrx_132b,
         musicgen_large,
         xlstm_1_3b,
+        deepseek_v2_lite,
     )
 }
 
@@ -58,7 +62,16 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
     if cfg.moe is not None:
         # capacity_factor 8 => effectively dropless at smoke-test scale, so
         # train-vs-decode consistency checks are exact (dropping is a
-        # legitimate train/serve divergence in capacity-bounded MoE).
-        kw["moe"] = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32,
-                              capacity_factor=8.0)
+        # legitimate train/serve divergence in capacity-bounded MoE). A
+        # dropless model stays dropless, with its shared experts and gates.
+        kw["moe"] = MoEConfig(
+            num_experts=8, top_k=2, d_ff_expert=32,
+            capacity_factor=8.0 if cfg.moe.capacity_factor else None,
+            num_shared_experts=cfg.moe.num_shared_experts,
+            norm_topk_prob=cfg.moe.norm_topk_prob)
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(cfg.mla, kv_lora_rank=32,
+                                        qk_nope_head_dim=16,
+                                        qk_rope_head_dim=8, v_head_dim=16)
+        kw["head_dim"] = 24
     return cfg.with_(**kw)

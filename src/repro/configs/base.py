@@ -9,8 +9,10 @@ stack whose per-layer *kind* is given by ``block_pattern`` repeated over
   ``rglru``  RG-LRU recurrent block (Griffin-style) + gated MLP
   ``mlstm``  mLSTM block (matrix memory, chunkwise-parallel), self-contained
   ``slstm``  sLSTM block (scalar memory, sequential recurrence), self-contained
+  ``mla``    multi-head latent attention (``mla``) + gated MLP
 
-MoE replaces the dense MLP in ``attn``/``local`` blocks when ``moe`` is set.
+MoE replaces the dense MLP in ``attn``/``local``/``mla`` blocks when ``moe``
+is set, except in the ``first_k_dense`` leading layers, which keep it.
 """
 from __future__ import annotations
 
@@ -24,7 +26,10 @@ class MoEConfig:
     num_experts: int
     top_k: int
     d_ff_expert: int
-    capacity_factor: float = 1.25
+    # None: dropless (every routed token reaches its expert, as in a
+    # published model that sets no capacity); a number bounds each
+    # expert's rows per group and drops the rest
+    capacity_factor: Optional[float] = 1.25
     router_jitter: float = 0.0
     # number of token groups used for sort-based dispatch; 0 -> one group per
     # data shard (set at lowering time from the mesh).
@@ -33,6 +38,47 @@ class MoEConfig:
     # buffers with all-to-alls (vs the default expert-TP which keeps dispatch
     # local and reduces over the model axis). EXPERIMENTS.md §Perf i5.
     expert_parallel: bool = False
+    # shared experts every token passes through, as one gated MLP of
+    # num_shared_experts * d_ff_expert, added to the routed output
+    num_shared_experts: int = 0
+    # renormalize the top-k gates to sum to 1 (False: the raw softmax
+    # probabilities of the chosen experts)
+    norm_topk_prob: bool = True
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    frequencies between the ``beta_fast`` and ``beta_slow`` correction
+    dimensions are interpolated by ``factor``, and attention logits scale
+    by mscale(mscale_all_dim)^2 with mscale(m) = 0.1 m ln(factor) + 1."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) without
+    query compression: keys and values come from a shared latent of
+    ``kv_lora_rank`` plus one rotary key head of ``qk_rope_head_dim``."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_scaling: Optional[YarnScaling] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token holds in the cache: the latent and its rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -57,6 +103,8 @@ class ModelConfig:
     act: str = "silu"                 # silu | gelu
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0            # leading layers with a dense MLP (MoE models)
+    mla: Optional[MLAConfig] = None   # the ``mla`` block kind's dimensions
     # --- audio (musicgen) ---
     num_codebooks: int = 0            # >0: multi-codebook output heads
     input_mode: str = "tokens"        # tokens | embeddings (modality stub)
@@ -107,13 +155,22 @@ class ModelConfig:
                 n += self.num_codebooks * self.vocab_size * d
             else:
                 n += emb
-        for kind in self.layer_kinds():
-            if kind in ("attn", "local"):
-                n += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        for i, kind in enumerate(self.layer_kinds()):
+            if kind in ("attn", "local", "mla"):
+                if kind == "mla":
+                    m = self.mla
+                    h = self.num_heads
+                    n += d * h * m.qk_head_dim + d * m.latent_dim
+                    n += m.kv_lora_rank * h * (m.qk_nope_head_dim
+                                               + m.v_head_dim)
+                    n += h * m.v_head_dim * d + m.kv_lora_rank
+                else:
+                    n += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
                 if self.qkv_bias:
                     n += self.q_dim + 2 * self.kv_dim
-                if self.moe is not None:
+                if self.moe is not None and i >= self.first_k_dense:
                     e = self.moe.top_k if active_only else self.moe.num_experts
+                    e += self.moe.num_shared_experts
                     n += d * self.moe.num_experts  # router
                     n += e * 3 * d * self.moe.d_ff_expert
                 else:

@@ -81,17 +81,22 @@ def _merge(acc, m, l, acc2, m2, l2):
 
 
 def chunked_causal_attention(q, k, v, positions, *, window: int = 0,
-                             q_chunk: int = 0) -> jnp.ndarray:
+                             q_chunk: int = 0,
+                             scale: Optional[float] = None) -> jnp.ndarray:
     """Flash-style causal attention in pure JAX.
 
-    q: [B,S,H,hd], k/v: [B,S,K,hd] (GQA: H = K*G), positions: [S].
+    q/k: [B,S,H,hd] / [B,S,K,hd], v: [B,S,K,dv] (GQA: H = K*G; the value
+    width may differ from the query/key width), positions: [S].
     window > 0: sliding-window (each query sees the last `window` keys).
-    Returns [B,S,H,hd].
+    scale: the softmax's factor on q.k, 1/sqrt(hd) unless given.
+    Returns [B,S,H,dv].
     """
     B, S, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qc = q_chunk or (2048 if S >= 8192 else min(S, 1024))
     qc = min(qc, S)
     assert S % qc == 0, (S, qc)
@@ -132,7 +137,7 @@ def chunked_causal_attention(q, k, v, positions, *, window: int = 0,
                 acc2, m2, l2 = _chunk_attend(qi, kj, vj, qp, kposj, scale)
                 return _merge(acc, m, l, acc2, m2, l2), None
 
-            acc0 = jnp.zeros((B, K, G, qc, hd), v.dtype)
+            acc0 = jnp.zeros((B, K, G, qc, dv), v.dtype)
             m0 = jnp.full((B, K, G, qc), NEG_INF, jnp.float32)
             l0 = jnp.zeros((B, K, G, qc), jnp.float32)
             (acc, m, l), _ = jax.lax.scan(
@@ -141,7 +146,7 @@ def chunked_causal_attention(q, k, v, positions, *, window: int = 0,
             outs.append(oi)
 
     out = jnp.concatenate(outs, axis=3)  # [B,K,G,S,hd] concat on q dim
-    out = out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, dv)
     return out
 
 

@@ -75,7 +75,12 @@ class Replica:
             spans.add("serve.decode_steps", max_new - 1)
             spans.add("serve.decode_rows", B * (max_new - 1))
             with spans.span("serve.fetch"):
-                return np.stack([np.asarray(t) for t in toks], axis=1)
+                out = np.stack([np.asarray(t) for t in toks], axis=1)
+                if "moe_stats" in cache:
+                    hit, rows = np.asarray(cache["moe_stats"]).tolist()
+                    spans.add("serve.moe_experts_hit", hit)
+                    spans.add("serve.moe_max_expert_rows", rows)
+                return out
         finally:
             self.outstanding -= prompt.size + max_new
 

@@ -30,7 +30,12 @@ lock, since replicas may serve from threads of their own:
 - ``serve.prefills``, ``serve.prompt_tokens``: prefill calls and their
   batch x prompt tokens, pads included;
 - ``serve.decode_steps``, ``serve.decode_rows``: decode steps run
-  (answer length - 1 per call) and batch rows over them.
+  (answer length - 1 per call) and batch rows over them;
+- ``serve.moe_experts_hit``, ``serve.moe_max_expert_rows`` (models with
+  experts): over the decode steps and MoE layers, the experts that got at
+  least one row and the busiest expert's rows. The decode program sums
+  them into its cache (``cache["moe_stats"]``); the replica reads them
+  with a round's tokens, so they cost no program or host sync a step.
 """
 from __future__ import annotations
 
@@ -41,7 +46,8 @@ from typing import Dict
 
 COUNTERS = ("serve.requests_queued", "serve.rounds",
             "serve.requests_batched", "serve.queue_wait_s", "serve.prefills",
-            "serve.prompt_tokens", "serve.decode_steps", "serve.decode_rows")
+            "serve.prompt_tokens", "serve.decode_steps", "serve.decode_rows",
+            "serve.moe_experts_hit", "serve.moe_max_expert_rows")
 
 _counts: Dict[str, float] = dict.fromkeys(COUNTERS, 0)
 _lock = threading.Lock()

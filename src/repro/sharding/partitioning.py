@@ -201,7 +201,7 @@ def cache_specs(cache_tree, cfg: ModelConfig, mesh: Mesh, *, tp: int = 0):
                     spec[off + 2] = "model"
                 elif L % msz == 0 and L >= 8192:
                     spec[off + 1] = "model"
-        elif name == "pos":
+        elif name in ("pos", "moe_stats"):
             pass
         elif name in ("h", "conv") and shape[-1] in (cfg.lru_width,):
             bs = data_spec(mesh, shape, batch_dim=off, tp=tp)

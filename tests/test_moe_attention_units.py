@@ -45,6 +45,30 @@ def test_moe_matches_dense_oracle_when_dropless():
     assert float(aux["moe_lb"]) > 0.0
 
 
+def _router_precisions(cfg, p, x):
+    """The precision of each matmul onto the router's [D, E] kernel."""
+    jaxpr = jax.make_jaxpr(lambda p, x: moe_forward(p, x, cfg))(p, x)
+    E = cfg.moe.num_experts
+    return [e.params["precision"] for e in jaxpr.eqns
+            if e.primitive.name == "dot_general"
+            and e.invars[1].aval.shape == (cfg.d_model, E)]
+
+
+def test_router_precision_follows_the_path():
+    """The capacity path's router keeps the default matmul precision; only
+    the dropless path asks for HIGHEST."""
+    import dataclasses
+    cfg = reduced_config(ARCHS["qwen3-moe-30b-a3b"])
+    from repro.models.moe import init_moe
+    p = init_moe(KEY, cfg, jnp.float32)
+    x = jnp.ones((1, 8, cfg.d_model), jnp.float32)
+    assert _router_precisions(cfg, p, x) == [None]
+    dropless = cfg.with_(moe=dataclasses.replace(cfg.moe,
+                                                 capacity_factor=None))
+    highest = jax.lax.Precision.HIGHEST
+    assert _router_precisions(dropless, p, x) == [(highest, highest)]
+
+
 def test_dispatch_drops_beyond_capacity():
     E, cap, d = 4, 2, 8
     n, k = 6, 1
@@ -125,6 +149,32 @@ def test_chunked_attention_chunk_size_invariance():
     a = chunked_causal_attention(q, k, v, pos, q_chunk=32)
     b = chunked_causal_attention(q, k, v, pos, q_chunk=128)
     assert float(jnp.max(jnp.abs(a - b))) < 2e-5
+
+
+@pytest.mark.parametrize("window", [0, 24])
+def test_chunked_attention_takes_a_value_width_and_a_scale(window):
+    """A value width other than the query/key width and an explicit
+    softmax scale (latent attention's prefill: qk 24, v 16 here) match the
+    naive attention; the old call, without them, gives bit for bit what
+    the explicit 1/sqrt(hd) gives."""
+    from repro.models.attention import chunked_causal_attention
+    B, S, H, K, hd, dv, scale = 2, 64, 4, 2, 24, 16, 0.37
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, K, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, K, dv), jnp.float32)
+    pos = jnp.arange(S)
+    out = chunked_causal_attention(q, k, v, pos, window=window, q_chunk=16,
+                                   scale=scale)
+    assert out.shape == (B, S, H, dv)
+    ref = naive_causal_attention(q * scale * math.sqrt(hd), k, v, pos,
+                                 window=window)
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
+    old = chunked_causal_attention(q, k, k, pos, window=window, q_chunk=16)
+    explicit = chunked_causal_attention(q, k, k, pos, window=window,
+                                        q_chunk=16,
+                                        scale=1.0 / math.sqrt(hd))
+    np.testing.assert_array_equal(np.asarray(old), np.asarray(explicit))
 
 
 # --------------------------------------------------------- optimizer units

@@ -162,6 +162,60 @@ def test_served_decode_step_updates_its_cache_in_place(one_chip, max_len):
     assert _slab_copies(undonated.as_text(), slab)
 
 
+def _v2lite(one_chip):
+    from repro.configs import ARCHS
+    from repro.models import model as M
+    cfg = ARCHS["deepseek-v2-lite"].with_(num_layers=7)
+    params = _on(jax.eval_shape(lambda k: M.init_params(k, cfg),
+                                jax.random.PRNGKey(0)), one_chip)
+    return cfg, params
+
+
+@pytest.mark.parametrize("B", [16, 3])
+def test_v2lite_decode_step_updates_its_latent_cache_in_place(one_chip, B):
+    """The replica's decode program at the deepseek-v2-lite serving cell's
+    widths (7 layers, the longdoc cache of 4224 positions; batch 16, and 3,
+    whose 18 routed rows the grouped matmul pads to whole tiles): the
+    donated latent cache is aliased to the one returned, the step needs
+    no cache-sized scratch (nor a copy of a layer's experts for the
+    grouped matmul), and no op copies a layer's latent slab in HBM (a
+    prefetch into the chip's fast memory, S(1), is not a copy)."""
+    from repro.models import model as M
+    from repro.runtime.serving_pool import serve_programs
+    cfg, params = _v2lite(one_chip)
+    L = 4224
+    cache = _on(M.init_cache(cfg, B, L, abstract=True), one_chip)
+    assert set(cache) == {"repeats", "tail", "lead", "moe_stats"}
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _, decode = serve_programs(cfg)
+    compiled = decode.lower(params, cache, tok, pos).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    assert 0 <= mem.alias_size_in_bytes - cache_bytes < 1e-3 * cache_bytes
+    assert mem.temp_size_in_bytes < 0.05 * cache_bytes, mem.temp_size_in_bytes
+    slab = f",{L},{cfg.mla.latent_dim}]"
+    copies = [c for c in _slab_copies(compiled.as_text(), slab)
+              if "S(1)} copy" not in c and "S(1)}, " not in c]
+    assert copies == []
+    _fits(compiled)
+
+
+def test_v2lite_longdoc_prefill_fits_one_chip(one_chip):
+    """The longdoc round's prefill at full batch (16 prompts of 4096 into
+    caches of 4224) fits one chip beside the 8 GB of weights: the MoE and
+    the dense MLP run over blocks of rows."""
+    from repro.runtime.serving_pool import serve_programs
+    cfg, params = _v2lite(one_chip)
+    prefill, _ = serve_programs(cfg)
+    tokens = jax.ShapeDtypeStruct((16, 4096), jnp.int32, sharding=one_chip)
+    compiled = prefill.lower(params, tokens, 4224).compile()
+    used = _fits(compiled)
+    assert used > 8e9                # the weights are all there
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e9
+
+
 # ------------------------------------------------------------------ kernels
 
 
