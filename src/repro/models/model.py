@@ -74,7 +74,7 @@ def _join_experts(rep_params, whole):
 
 
 # the decode step's routing counts, summed into cache["moe_stats"]
-MOE_STATS = ("moe_hit", "moe_max_rows")
+MOE_STATS = ("moe_hit", "moe_max_rows", "moe_kernel_layers")
 # prefill runs a MLA layer's MLP or MoE over blocks of this many rows, so
 # that a long batch's expert and MLP activations fit beside the weights
 PREFILL_ROW_BLOCK = 8192

@@ -16,13 +16,18 @@ An expert-parallel all-to-all variant is explored in the perf hillclimb.
 
 The dropless path (``capacity_factor`` None) sorts every (token, expert)
 pair by expert and runs each expert's rows as one group of a grouped
-matmul (``jax.lax.ragged_dot``), so no token is dropped and no token's
-result depends on its batch-mates. Shared experts, where configured, are
-one gated MLP every token passes through, added to the routed output.
+matmul, so no token is dropped and no token's result depends on its
+batch-mates. A call of few rows, as a decode step is, runs them through
+the few-row expert kernel (``kernels/moe_decode``), which reads each hit
+expert's weights once in large blocks; a call of many (prefill, training)
+through ``jax.lax.ragged_dot``. Shared experts, where configured, are one
+gated MLP every token passes through, added to the routed output.
 
 Every call also counts, per layer, the experts that the router chose for
-at least one token (``moe_hit``) and the rows of the busiest one
-(``moe_max_rows``); the decode step sums them into its cache.
+at least one token (``moe_hit``), the rows of the busiest one
+(``moe_max_rows``) and, on the dropless path, whether the few-row kernel
+ran its experts (``moe_kernel_layers``); the decode step sums them into
+its cache.
 """
 from __future__ import annotations
 
@@ -33,6 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.moe_decode import expert_mlp
+from repro.kernels.moe_decode.kernel import window_rows
 from repro.models.layers import activation, dense, init_dense, init_mlp, mlp
 
 
@@ -121,6 +128,24 @@ def _route(p, x, m, precision=None):
 
 EXPERT_WEIGHTS = ("wi_gate", "wi_up", "wo")
 
+# The few-row kernel multiplies each hit expert's weights (2 bytes a
+# weight in bfloat16) by a window of ``window_rows(N)`` rows (2 flops a
+# weight a row): that many flops a byte. v5e's ridge point is 197 TFLOP/s
+# over 819 GB/s, 240 flops a byte; a window of at most one 128-row MXU
+# pass keeps it bound by the weights' bytes with room to spare. Calls
+# with more rows give each expert enough of them to fill ragged_dot's
+# tiles, and keep it. The bound is derived, not measured: the chip sweep
+# (benchmarks/moe_decode_tiles.py) covers calls of 1-16 tokens, and no
+# serving cell makes a call of 17-113.
+FEW_ROWS_WINDOW = 128
+
+
+def few_rows(n_tokens: int) -> bool:
+    """Whether a dropless call over ``n_tokens`` tokens runs its experts
+    through the few-row kernel. Top-k picks distinct experts, so an
+    expert gets at most ``n_tokens`` rows."""
+    return window_rows(n_tokens) <= FEW_ROWS_WINDOW
+
 
 def dropless_routed(p, x, top_p, top_i, act, num_experts: int):
     """Every token's gate-weighted sum over its top-k experts, none dropped.
@@ -128,35 +153,49 @@ def dropless_routed(p, x, top_p, top_i, act, num_experts: int):
     x: [N, D]; top_p, top_i: [N, k]. The N*k (token, expert) rows are
     sorted by expert and each expert's rows run as one group of a grouped
     matmul; the rows are put back in token order (the inverse of the sort)
-    and summed over k in float32.
+    and summed over k in float32. Where ``few_rows(N)``, the few-row
+    kernel runs the groups, each hit expert's weights read once; else
+    ``jax.lax.ragged_dot``.
 
     Where ``p`` holds ``layer``, its expert weights are the whole stack of
-    the scanned layers [R, E, ...]: the grouped matmul then reads layer
-    ``layer``'s experts in place, as groups R*E of which only that layer's
-    have rows, instead of from a slice of the stack copied for it. The
-    TPU compiler refuses that form unless the rows fill whole tiles of 8:
-    pad rows, computed by the layer's last expert, make them up and are
-    dropped after."""
+    the scanned layers [R, E, ...]: the experts of layer ``layer`` are
+    read there in place, instead of from a slice of the stack copied for
+    it. For ragged_dot they are groups R*E of which only that layer's have
+    rows; the TPU compiler refuses that form unless the rows fill whole
+    tiles of 8, so pad rows, computed by the layer's last expert, make
+    them up and are dropped after."""
     N, D = x.shape
     k = top_i.shape[-1]
     flat_e = top_i.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
     rows = jnp.take(x, order // k, axis=0)                      # [N*k, D]
     sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
-    wg, wu, wo = (p[n].astype(x.dtype) for n in EXPERT_WEIGHTS)
+    if few_rows(N):
+        ws = [p[n] for n in EXPERT_WEIGHTS]
+        if "layer" not in p:
+            ws = [w[None] for w in ws]
+        y = expert_mlp(rows, sizes, *ws, p.get("layer", 0), act=act,
+                       max_rows=N)
+    else:
+        y = _ragged_routed(p, rows, sizes, flat_e, act, num_experts)
+    y = jnp.take(y[:N * k], jnp.argsort(order), axis=0).reshape(N, k, D)
+    return jnp.einsum("nkd,nk->nd", y, top_p.astype(jnp.float32))
+
+
+def _ragged_routed(p, rows, sizes, flat_e, act, num_experts: int):
+    """The sorted rows' expert outputs [>= N*k, D] by ragged_dot."""
+    wg, wu, wo = (p[n].astype(rows.dtype) for n in EXPERT_WEIGHTS)
     if "layer" in p:
         groups = wg.shape[0] * num_experts
         wg, wu, wo = (w.reshape((groups,) + w.shape[2:]) for w in (wg, wu, wo))
-        pad = -N * k % 8
+        pad = -rows.shape[0] % 8
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
         sizes = jnp.bincount(flat_e + p["layer"] * num_experts,
                              length=groups).astype(jnp.int32)
         sizes = sizes.at[(p["layer"] + 1) * num_experts - 1].add(pad)
     h = act(jax.lax.ragged_dot(rows, wg, sizes)) \
         * jax.lax.ragged_dot(rows, wu, sizes)
-    y = jax.lax.ragged_dot(h, wo, sizes, preferred_element_type=jnp.float32)
-    y = jnp.take(y[:N * k], jnp.argsort(order), axis=0).reshape(N, k, D)
-    return jnp.einsum("nkd,nk->nd", y, top_p.astype(jnp.float32))
+    return jax.lax.ragged_dot(h, wo, sizes, preferred_element_type=jnp.float32)
 
 
 def moe_forward(p, x, cfg: ModelConfig, *, num_groups: int = 0,
@@ -211,5 +250,6 @@ def _moe_dropless(p, x, cfg: ModelConfig):
                         m.num_experts)
     if "shared" in p:
         y = y + mlp(p["shared"], xf, cfg.act).astype(jnp.float32)
-    return (y.astype(x.dtype).reshape(B, S, D),
-            _aux(router_logits, router_probs, top_i, m))
+    aux = _aux(router_logits, router_probs, top_i, m)
+    aux["moe_kernel_layers"] = jnp.int32(few_rows(B * S))
+    return y.astype(x.dtype).reshape(B, S, D), aux

@@ -77,9 +77,11 @@ class Replica:
             with spans.span("serve.fetch"):
                 out = np.stack([np.asarray(t) for t in toks], axis=1)
                 if "moe_stats" in cache:
-                    hit, rows = np.asarray(cache["moe_stats"]).tolist()
+                    hit, rows, kernel = np.asarray(
+                        cache["moe_stats"]).tolist()
                     spans.add("serve.moe_experts_hit", hit)
                     spans.add("serve.moe_max_expert_rows", rows)
+                    spans.add("serve.moe_kernel_layers", kernel)
                 return out
         finally:
             self.outstanding -= prompt.size + max_new

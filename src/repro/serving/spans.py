@@ -35,7 +35,12 @@ lock, since replicas may serve from threads of their own:
   experts): over the decode steps and MoE layers, the experts that got at
   least one row and the busiest expert's rows. The decode program sums
   them into its cache (``cache["moe_stats"]``); the replica reads them
-  with a round's tokens, so they cost no program or host sync a step.
+  with a round's tokens, so they cost no program or host sync a step;
+- ``serve.moe_kernel_layers`` (models with dropless experts): over the
+  decode steps, the MoE layers whose routed experts ran through the
+  few-row expert kernel (``kernels/moe_decode``; their jnp reference off
+  a TPU), summed and read as the two above. Engagement is this over
+  ``serve.decode_steps`` x the MoE layers.
 """
 from __future__ import annotations
 
@@ -47,7 +52,8 @@ from typing import Dict
 COUNTERS = ("serve.requests_queued", "serve.rounds",
             "serve.requests_batched", "serve.queue_wait_s", "serve.prefills",
             "serve.prompt_tokens", "serve.decode_steps", "serve.decode_rows",
-            "serve.moe_experts_hit", "serve.moe_max_expert_rows")
+            "serve.moe_experts_hit", "serve.moe_max_expert_rows",
+            "serve.moe_kernel_layers")
 
 _counts: Dict[str, float] = dict.fromkeys(COUNTERS, 0)
 _lock = threading.Lock()

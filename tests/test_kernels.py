@@ -129,3 +129,95 @@ def test_mlstm_chunkwise_matches_stepwise_decode():
     y_pre, cache_pre = xl.mlstm_block_prefill(p, x[:, :-1], cfg, chunk=5)
     y_last, _ = xl.mlstm_block_decode(p, x[:, -1:], cache_pre, cfg)
     assert float(jnp.max(jnp.abs(y_last - y_seq[:, -1:]))) < 1e-3
+
+
+# ------------------------------------------------------------ moe decode
+
+
+def _routing(case, N, E=64, k=6):
+    """[N, k] distinct experts per token for each routing case."""
+    perm = jax.vmap(lambda kk: jax.random.permutation(kk, E))(
+        jax.random.split(jax.random.fold_in(KEY, N), N))
+    if case == "every_token_hits_expert_7":
+        rest = jnp.where(perm == 7, perm[:, k:k + 1], perm)
+        return jnp.concatenate([jnp.full((N, 1), 7), rest[:, :k - 1]], 1)
+    if case == "all_but_one_token_on_experts_0_5":
+        same = jnp.broadcast_to(jnp.arange(k), (N, k))
+        return same.at[-1].set(jnp.arange(E - k, E))
+    return perm[:, :k]
+
+
+@pytest.mark.parametrize("case,N,R,layer", [
+    ("random", 1, 1, 0),
+    ("random", 4, 1, 0),
+    ("random", 16, 1, 0),
+    ("every_token_hits_expert_7", 16, 1, 0),
+    ("all_but_one_token_on_experts_0_5", 16, 1, 0),
+    ("random", 4, 3, 0),              # a layer of the whole stack
+    ("random", 4, 3, 1),
+    ("random", 16, 3, 2),
+])
+def test_moe_decode_matches_ragged_dot_and_dense_reference(case, N, R, layer):
+    """Top-6 of 64 experts at widths of whole 128-lane tiles (F = 3 x 128,
+    like 1408 = 11 x 128), in bfloat16: the kernel in the interpreter
+    against the dense per-token reference, and both against ragged_dot over
+    the stack's R*E groups as prefill computes it. Most experts get no
+    row."""
+    from repro.kernels.moe_decode import expert_mlp, expert_mlp_reference
+    E, D, F, k = 64, 256, 384, 6
+    ks = jax.random.split(jax.random.fold_in(KEY, 99), 4)
+    wg, wu = (jax.random.normal(kk, (R, E, D, F)) / D ** 0.5
+              for kk in ks[:2])
+    wo = jax.random.normal(ks[2], (R, E, F, D)) / F ** 0.5
+    wg, wu, wo = (w.astype(jnp.bfloat16) for w in (wg, wu, wo))
+    x = jax.random.normal(ks[3], (N, D)).astype(jnp.bfloat16)
+    top_i = _routing(case, N)
+    assert all(len(set(r)) == k for r in top_i.tolist())
+    flat = top_i.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    rows = x[order // k]
+    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    assert int(jnp.sum(sizes == 0)) > 0
+
+    got = expert_mlp(rows, sizes, wg, wu, wo, jnp.int32(layer),
+                     act=jax.nn.silu, max_rows=N, impl="interpret",
+                     tk=128, tn=128)
+    ref = expert_mlp_reference(rows, sizes, wg, wu, wo, jnp.int32(layer),
+                               act=jax.nn.silu)
+    grouped = jnp.bincount(flat + layer * E, length=R * E).astype(jnp.int32)
+    flat_w = [w.reshape((R * E,) + w.shape[2:]) for w in (wg, wu, wo)]
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, flat_w[0], grouped)) \
+        * jax.lax.ragged_dot(rows, flat_w[1], grouped)
+    ragged = jax.lax.ragged_dot(h, flat_w[2], grouped,
+                                preferred_element_type=jnp.float32)
+    scale = float(jnp.max(jnp.abs(ref)))
+    assert got.shape == ref.shape == (N * k, D) and got.dtype == jnp.float32
+    # the kernel and the reference round act(g) * u once, from float32
+    # sums taken in another order; ragged_dot rounds g and u first
+    assert float(jnp.max(jnp.abs(got - ref))) < 1e-2 * scale
+    assert float(jnp.max(jnp.abs(got - ragged))) < 3e-2 * scale
+    assert float(jnp.max(jnp.abs(ref - ragged))) < 3e-2 * scale
+
+
+@pytest.mark.parametrize("N,kernel", [(1, True), (16, True), (113, True),
+                                      (114, False), (2048, False)])
+def test_dropless_routed_takes_the_kernel_for_few_rows(N, kernel,
+                                                      monkeypatch):
+    """A call of few tokens (a decode step's) holds the few-row kernel and
+    no ragged_dot; one of many (a prefill row block's) only ragged_dot.
+    Traced as for a TPU, where the kernel is the Pallas one."""
+    from repro.kernels.moe_decode import ops
+    from repro.models.moe import dropless_routed, few_rows
+    monkeypatch.setattr(ops, "AUTO_IMPL", "pallas")
+    E, D, F, k = 64, 256, 128, 6
+    p = {"wi_gate": jnp.zeros((3, E, D, F), jnp.bfloat16),
+         "wi_up": jnp.zeros((3, E, D, F), jnp.bfloat16),
+         "wo": jnp.zeros((3, E, F, D), jnp.bfloat16),
+         "layer": jnp.int32(1)}
+    jaxpr = str(jax.make_jaxpr(
+        lambda p, x, tp, ti: dropless_routed(p, x, tp, ti, jax.nn.silu, E))(
+        p, jnp.zeros((N, D), jnp.bfloat16), jnp.zeros((N, k)),
+        jnp.zeros((N, k), jnp.int32)))
+    assert few_rows(N) == kernel
+    assert ("pallas_call" in jaxpr) == kernel
+    assert ("ragged_dot" in jaxpr) == (not kernel)

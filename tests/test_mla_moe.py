@@ -235,3 +235,27 @@ def test_dense_models_have_no_counters_in_their_cache():
                          max_len=6)
     assert set(cache) == {"repeats", "tail"}
     assert set(M.init_cache(cfg, 1, 6)) == {"repeats", "tail"}
+
+
+@pytest.mark.parametrize("arch,per_step", [("deepseek-v2-lite", 6),
+                                           ("deepseek-7b", 0)])
+def test_kernel_layers_counter_counts_each_moe_layer_of_each_step(arch,
+                                                                  per_step):
+    """At V2-Lite's served depth (a dense layer, then 6 MoE layers) every
+    decode step adds 6 to ``serve.moe_kernel_layers``: each MoE layer's
+    few routed rows take the few-row expert path. A dense model's steps
+    add nothing, and its cache holds no counters."""
+    from repro.runtime.serving_pool import init_host_params
+    cfg = reduced_config(ARCHS[arch]).with_(num_layers=7)
+    pool = ServingPool(cfg, init_host_params(cfg, seed=2),
+                       capacity_tokens_per_replica=float("inf"))
+    pool.scale_to(jax.devices()[:1])
+    B, P, new = 2, 5, 4
+    prompt = np.random.default_rng(3).integers(0, 256, (B, P)).astype(np.int32)
+    spans.reset()
+    pool.submit(prompt, new)
+    c = spans.snapshot()
+    spans.reset()
+    assert c["serve.decode_steps"] == new - 1
+    assert c["serve.moe_kernel_layers"] == per_step * (new - 1)
+    assert (c["serve.moe_experts_hit"] > 0) == (per_step > 0)

@@ -8,6 +8,7 @@ results or times. The topology is described inside a fixture, never at
 import, so that under several test workers only the worker given this file
 loads the TPU library.
 """
+import functools
 import os
 import re
 
@@ -33,6 +34,15 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _kernels_as_on_a_tpu(monkeypatch):
+    """Programs here are lowered for a TPU from a host that may have none:
+    the kernels that pick their implementation by the default backend
+    take the Pallas one, as on the chip."""
+    from repro.kernels.moe_decode import ops
+    monkeypatch.setattr(ops, "AUTO_IMPL", "pallas")
 
 
 def _on(tree, sharding):
@@ -175,10 +185,10 @@ def _v2lite(one_chip):
 def test_v2lite_decode_step_updates_its_latent_cache_in_place(one_chip, B):
     """The replica's decode program at the deepseek-v2-lite serving cell's
     widths (7 layers, the longdoc cache of 4224 positions; batch 16, and 3,
-    whose 18 routed rows the grouped matmul pads to whole tiles): the
-    donated latent cache is aliased to the one returned, the step needs
-    no cache-sized scratch (nor a copy of a layer's experts for the
-    grouped matmul), and no op copies a layer's latent slab in HBM (a
+    whose 18 routed rows fill no whole number of tiles): the donated
+    latent cache is aliased to the one returned, the step needs no
+    cache-sized scratch (nor a copy of a layer's experts for the expert
+    kernels), and no op copies a layer's latent slab in HBM (a
     prefetch into the chip's fast memory, S(1), is not a copy)."""
     from repro.models import model as M
     from repro.runtime.serving_pool import serve_programs
@@ -200,6 +210,32 @@ def test_v2lite_decode_step_updates_its_latent_cache_in_place(one_chip, B):
               if "S(1)} copy" not in c and "S(1)}, " not in c]
     assert copies == []
     _fits(compiled)
+
+
+@pytest.mark.parametrize("B", [1, 16])
+def test_v2lite_decode_step_runs_its_experts_through_the_kernels(one_chip, B):
+    """The replica's decode program at the deepseek-v2-lite serving cell's
+    widths and longdoc cache (4224 positions), at batch 1 and 16 (6 and 96
+    routed rows): the routed experts run through the few-row expert
+    kernel, no ragged_dot is left, and it fits one chip. The longdoc
+    round's prefill keeps ragged_dot."""
+    from repro.models import model as M
+    from repro.runtime.serving_pool import serve_programs
+    cfg, params = _v2lite(one_chip)
+    cache = _on(M.init_cache(cfg, B, 4224, abstract=True), one_chip)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    prefill, decode = serve_programs(cfg)
+    compiled = decode.lower(params, cache, tok, pos).compile()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and "custom_call_target" in line]
+    assert any("moe_decode" in k for k in kernels), kernels
+    assert "ragged" not in hlo
+    _fits(compiled)
+    tokens = jax.ShapeDtypeStruct((16, 4096), jnp.int32, sharding=one_chip)
+    lowered = prefill.lower(params, tokens, 4224).as_text()
+    assert "ragged_dot" in lowered and "moe_decode" not in lowered
 
 
 def test_v2lite_longdoc_prefill_fits_one_chip(one_chip):
@@ -236,6 +272,18 @@ def _kernel_case(name, one_chip):
     if name == "flash_attention":        # head_dim 256, 10 heads
         from repro.kernels.flash_attention.kernel import flash_attention_fwd
         return flash_attention_fwd, (sds((10, 2048, 256)),) * 3
+    if name == "moe_decode":             # deepseek-v2-lite: 16 tokens, top-6
+        from repro.kernels.moe_decode.kernel import (expert_mlp_fwd,
+                                                     window_rows)
+        N, S, R, E, D, F = 16, 64, 6, 64, 2048, 1408
+        Mp = N * 6 + window_rows(N)
+        i32 = functools.partial(sds, dtype=jnp.int32)
+        return (lambda x, ids, off, cnt, nhit, layer, wg, wu, wo:
+                expert_mlp_fwd(x, ids, off, cnt, nhit, layer, wg, wu, wo,
+                               act=jax.nn.silu, window=window_rows(N)),
+                (sds((Mp, D)), i32((S,)), i32((S,)), i32((S,)), i32((1,)),
+                 i32((1,)), sds((R, E, D, F)), sds((R, E, D, F)),
+                 sds((R, E, F, D))))
     from repro.kernels.decode_attention.kernel import decode_attention_fwd
     return (lambda q, k, v, sp, cp: decode_attention_fwd(q, k, v, sp, cp,
                                                           window=2048),
@@ -244,7 +292,8 @@ def _kernel_case(name, one_chip):
 
 
 @pytest.mark.parametrize("name", ["rglru_scan", "mlstm_chunk",
-                                  "flash_attention", "decode_attention"])
+                                  "flash_attention", "decode_attention",
+                                  "moe_decode"])
 def test_pallas_kernel_lowers_to_mosaic(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
@@ -258,4 +307,4 @@ def test_kernel_cases_cover_every_kernel():
     kernels = sorted(d for d in os.listdir(here)
                      if os.path.isfile(os.path.join(here, d, "kernel.py")))
     assert kernels == sorted(["rglru_scan", "mlstm_chunk", "flash_attention",
-                              "decode_attention"]), kernels
+                              "decode_attention", "moe_decode"]), kernels
