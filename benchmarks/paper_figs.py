@@ -20,7 +20,7 @@ from repro.core.experiment import (DC_SIZES, SC_TOTAL, run_experiment,
                                    validate_claims)
 from repro.core.traces import (WS_CAPACITY_RPS, synthetic_worldcup_load,
                                worldcup_demand_events)
-from repro.core.types import SimConfig
+from repro.core.types import SimConfig, paper_tenants
 from repro.core.ws_cms import demand_from_load
 
 _CACHE: Dict = {}
@@ -110,7 +110,8 @@ def request_level_slo() -> Tuple[float, Dict]:
                                slo=SLOConfig(latency_target_s=30.0))
     jobs = synthetic_sdsc_blue(seed=0, n_jobs=80, horizon=horizon,
                                max_nodes=32)
-    res = ConsolidationSim(SimConfig(total_nodes=64), jobs, workload,
+    res = ConsolidationSim(SimConfig(total_nodes=64),
+                           tenants=paper_tenants(jobs, workload),
                            horizon=horizon).run()
     dedicated = workload.realized_metrics([(0.0, 16)], horizon=horizon)
     us = (time.time() - t0) * 1e6
@@ -194,9 +195,12 @@ def campaign_throughput() -> Tuple[float, Dict]:
             SimConfig(total_nodes=cell.total_nodes,
                       preempt_mode=cell.preempt, scheduler=cell.scheduler,
                       seed=cell.seed),
-            jobs, wl, horizon=cell.horizon_s)
+            tenants=paper_tenants(jobs, wl), horizon=cell.horizon_s,
+            defer_queue=True)
         sim.run()
-        work.append((trace, list(sim.ws.alloc_events), slo, cell.horizon_s))
+        # the ws department's realized allocation (its queue is deferred)
+        _, _, alloc_events = sim.deferred_queue[0]
+        work.append((trace, alloc_events, slo, cell.horizon_s))
         pk = (cell.arrival, cell.slo_target_s, cell.rate_rps,
               cell.horizon_s, cell.seed)
         if pk not in planned_done:

@@ -19,7 +19,7 @@ import numpy as np
 from repro.configs import ARCHS, reduced_config
 from repro.configs.base import TrainConfig
 from repro.runtime.elastic import ElasticTrainer
-from repro.runtime.orchestrator import PhoenixOrchestrator
+from repro.runtime.orchestrator import MultiTenantOrchestrator
 from repro.runtime.serving_pool import ServingPool, init_host_params
 
 
@@ -36,15 +36,18 @@ def main(argv=None):
                              ckpt_dir=ckpt_dir, model_size=1)
     params = init_host_params(cfg, seed=0)
     pool = ServingPool(cfg, params, capacity_tokens_per_replica=200.0)
-    orch = PhoenixOrchestrator(trainer, pool, min_st_devices=2)
+    # the paper's two departments: ST (the trainer) and WS (the pool)
+    orch = MultiTenantOrchestrator(policy="paper")
+    orch.add_batch("st", trainer, priority=1, min_devices=2)
+    orch.add_latency("ws", pool, priority=0)
     orch.start()
 
     # WS offered load (tokens/interval): trough -> spike -> trough
     loads = np.interp(np.arange(args.intervals),
                       [0, 2, 3, args.intervals - 1], [0, 0, 900, 0])
     for i, load in enumerate(loads):
-        orch.ws_tick(float(load))
-        m = orch.train_steps(2)
+        orch.latency_tick("ws", float(load))
+        m = orch.train_steps("st", 2)
         print(f"interval {i}: ws_load={load:6.0f} "
               f"replicas={len(pool.replicas)} "
               f"train_devices={m['devices']} step={m['step']} "
@@ -53,7 +56,7 @@ def main(argv=None):
             out = pool.submit(np.array([[5, 6, 7, 8]], dtype=np.int32), 4)
             print(f"            served 1 request -> tokens {out[0].tolist()}")
     print(f"resizes: {trainer.resizes}; ST events: "
-          f"{[e for e in orch.events if e['kind'] == 'st_shrink']}")
+          f"{[e for e in orch.events if e['kind'] == 'shrink']}")
     print("final step:", trainer.step, "(no work lost across resizes)")
     return 0
 

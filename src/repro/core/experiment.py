@@ -23,7 +23,7 @@ from repro.core.simulator import ConsolidationSim, SimResult
 from repro.core.traces import (SDSC_BLUE_NODES, TWO_WEEKS_S,
                                WORLDCUP_PEAK_INSTANCES, synthetic_sdsc_blue,
                                worldcup_demand_events)
-from repro.core.types import Job, SimConfig
+from repro.core.types import Job, SimConfig, paper_tenants
 
 SC_TOTAL = SDSC_BLUE_NODES + WORLDCUP_PEAK_INSTANCES  # 208
 DC_SIZES = (200, 190, 180, 170, 160, 150)
@@ -35,7 +35,7 @@ def run_static(jobs: List[Job], *, cfg: Optional[SimConfig] = None,
     benefit is load-independent, so only the ST side needs simulating)."""
     cfg = dataclasses.replace(cfg or SimConfig(),
                               total_nodes=SDSC_BLUE_NODES)
-    sim = ConsolidationSim(cfg, jobs, ws_demand=[], horizon=horizon)
+    sim = ConsolidationSim(cfg, tenants=paper_tenants(jobs), horizon=horizon)
     return sim.run()
 
 
@@ -43,7 +43,8 @@ def run_dynamic(jobs: List[Job], ws_demand: List[Tuple[float, int]],
                 total_nodes: int, *, cfg: Optional[SimConfig] = None,
                 horizon: float = TWO_WEEKS_S) -> SimResult:
     cfg = dataclasses.replace(cfg or SimConfig(), total_nodes=total_nodes)
-    sim = ConsolidationSim(cfg, jobs, ws_demand=ws_demand, horizon=horizon)
+    sim = ConsolidationSim(cfg, tenants=paper_tenants(jobs, ws_demand),
+                           horizon=horizon)
     return sim.run()
 
 

@@ -289,10 +289,6 @@ class PolicyEngine:
         return grants
 
 
-# back-compat alias: the pre-engine name for the policy base class
-CooperativePolicy = PolicyEngine
-
-
 class PaperPolicy(PolicyEngine):
     """The paper's verbatim configuration: WS preempts, ALL idle to ST.
 
@@ -736,8 +732,6 @@ POLICIES: Dict[str, Callable[[], PolicyEngine]] = {
     BudgetAuctionEngine.name: BudgetAuctionEngine,
     SecondPriceEngine.name: SecondPriceEngine,
 }
-# alias: the registry IS the engine registry
-ENGINES = POLICIES
 
 
 def get_policy(policy) -> PolicyEngine:
@@ -753,16 +747,3 @@ def get_policy(policy) -> PolicyEngine:
             f"unknown cooperative policy {policy!r}; "
             f"have {sorted(POLICIES)}") from None
 
-
-# alias kept so call sites can say what they mean
-get_engine = get_policy
-
-
-def __getattr__(name):
-    # Historical home of the multi-tenant service (now built on the registry
-    # state machine in core/provision.py); re-exported lazily so the two
-    # modules can import in either order.
-    if name == "MultiTenantProvisionService":
-        from repro.core.provision import MultiTenantProvisionService
-        return MultiTenantProvisionService
-    raise AttributeError(name)

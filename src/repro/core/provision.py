@@ -20,18 +20,17 @@ per-tenant runtime signals):
     empty — a misattributed failure must never desync ``total`` from the
     pool sum).
 
-``ResourceProvisionService`` keeps the paper's literal two-tenant API
-(``st_alloc``/``ws_alloc``, ``on_grant_st``, ``force_st_release``, …) as a
-thin facade over a 2-tenant registry running the ``"paper"`` policy, so the
-2009 experiment stays reproducible bit-for-bit as the degenerate case.
+The paper's service is the degenerate case and has no class of its own:
+``TenantProvisionService(total, policy="paper")`` with the two specs of
+:func:`~repro.core.types.paper_tenants` registered (``st``, then ``ws``)
+keeps the 2009 experiment reproducible bit-for-bit.
 """
 from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
 from repro.core.nodes import DRAIN_POOL, NodeInventory, NodeState
-from repro.core.policies import (CooperativePolicy, PaperPolicy,
-                                 PolicyEngine, Tenant, get_policy)
+from repro.core.policies import PolicyEngine, Tenant, get_policy
 from repro.core.telemetry import NULL_TRACER, Tracer
 from repro.core.types import TenantSignals, TenantSpec
 
@@ -349,9 +348,6 @@ class TenantProvisionService:
         if provision:
             self.provision_idle()
 
-    # alias kept for the original multi-tenant API
-    set_batch_demand = set_demand
-
     def provision_idle(self):
         """Distribute free nodes to batch tenants per the cooperative
         policy (paper rule 2 is the ``"paper"`` policy's version)."""
@@ -479,85 +475,3 @@ class TenantProvisionService:
         self.provision_idle()   # re-provision before the invariant check:
         self.check()            # the repaired node may cover unmet demand
         return node
-
-
-class MultiTenantProvisionService(TenantProvisionService):
-    """Original multi-tenant API (strict priorities, greedy/demand-capped
-    idle) expressed over the policy framework. ``greedy_idle=True``
-    reproduces the paper's two-tenant rule verbatim (ALL leftover idle
-    nodes are dumped on the highest-priority batch tenant, demand or not);
-    the default caps grants at declared demand and leaves the remainder
-    free."""
-
-    def __init__(self, total_nodes: int, *, greedy_idle: bool = False):
-        super().__init__(
-            total_nodes,
-            policy="paper" if greedy_idle else "demand_capped")
-        self.greedy_idle = greedy_idle
-
-
-class ResourceProvisionService(TenantProvisionService):
-    """The paper's two-tenant service (§II-B), verbatim policy:
-
-      * WS demands have higher priority than ST demands.
-      * All idle resources are provisioned to ST.
-      * If WS claims urgent resources, the provision service FORCES ST to
-        return the claimed amount and reallocates it to WS.
-
-    Implemented as a fixed 2-tenant registry under the ``"paper"`` policy;
-    the legacy attribute/callback API is preserved so the simulator, the
-    runtime orchestrator and the seed experiments are bit-for-bit
-    unchanged.
-    """
-
-    def __init__(self, total_nodes: int, *,
-                 tracer: Optional[Tracer] = None):
-        super().__init__(total_nodes, policy=PaperPolicy(), tracer=tracer)
-        # registration order (st, ws) is a compatibility contract: node
-        # failures and timeline columns attribute in this order
-        self._st = self.register(Tenant("st", "batch", priority=1))
-        self._ws = self.register(Tenant("ws", "latency", priority=0))
-        self.on_grant_ws: Optional[Callable[[int], None]] = None
-
-    # ------------------------------------------------- legacy attributes
-    @property
-    def st_alloc(self) -> int:
-        return self._st.alloc
-
-    @property
-    def ws_alloc(self) -> int:
-        return self._ws.alloc
-
-    @property
-    def on_grant_st(self) -> Optional[Callable[[int], None]]:
-        return self._st.on_grant
-
-    @on_grant_st.setter
-    def on_grant_st(self, fn: Optional[Callable[[int], None]]):
-        self._st.on_grant = fn
-
-    @property
-    def force_st_release(self) -> Optional[Callable[[int], int]]:
-        return self._st.on_force_release
-
-    @force_st_release.setter
-    def force_st_release(self, fn: Optional[Callable[[int], int]]):
-        self._st.on_force_release = fn
-
-    # --------------------------------------------------- legacy verbs
-    def ws_request(self, n: int) -> int:
-        """WS claims n more nodes (urgent, highest priority)."""
-        return self.claim("ws", n)
-
-    def ws_release(self, n: int):
-        """WS releases idle nodes immediately (paper's WS policy)."""
-        self.release("ws", n)
-
-    def provision_idle_to_st(self):
-        """All idle resources go to ST (paper's provision policy, rule 2)."""
-        self.provision_idle()
-
-    def st_release(self, n: int):
-        """ST voluntarily returns nodes (idle beyond need); they stay free
-        until the next provisioning decision."""
-        self.release("st", n, reprovision=False)

@@ -5,14 +5,14 @@ department over a virtual-time event queue. Exact event ordering in virtual
 seconds — the paper's 100x wall-clock acceleration is irrelevant here (no
 wall-clock dependence at all).
 
-The paper's experiment is the degenerate 2-department case (one ST batch
-department + one WS latency department under the ``"paper"`` policy) and is
-what the legacy ``ConsolidationSim(cfg, jobs, ws_demand, horizon)`` call
-builds — bit-for-bit identical to the seed simulator (the regression test
-in tests/test_tenancy.py pins its numbers). Passing ``tenants=[TenantSpec,
-...]`` instead runs any department mix — e.g. 2 HPC + 2 request-level WS +
-1 best-effort batch tenant — under any cooperative policy from
-core/policies.py, with per-department accounting in ``SimResult.tenants``.
+``ConsolidationSim(cfg, tenants=[TenantSpec, ...], horizon=..., policy=...)``
+runs any department mix — e.g. 2 HPC + 2 request-level WS + 1 best-effort
+batch tenant — under any cooperative policy from core/policies.py, with
+per-department accounting in ``SimResult.tenants``. The paper's experiment
+is the degenerate 2-department case: ``tenants=paper_tenants(jobs,
+ws_demand)`` (core/types.py) under the default ``"paper"`` policy,
+bit-for-bit identical to the seed simulator (the regression test in
+tests/test_tenancy.py pins its numbers).
 
 Supports the paper's experiment (kill-mode, first-fit, SC vs DC) plus the
 beyond-paper knobs in ``SimConfig``: checkpoint-preemption, EASY backfill,
@@ -31,8 +31,7 @@ import numpy as np
 
 from repro.core.faults import FaultSpec, make_injector
 from repro.core.nodes import DRAIN_POOL, NodeInventory
-from repro.core.provision import (ResourceProvisionService,
-                                  TenantProvisionService)
+from repro.core.provision import TenantProvisionService
 from repro.core.st_cms import STServer
 from repro.core.telemetry import NULL_TRACER, Tracer
 from repro.core.types import (Event, EventKind, Job, JobState, SimConfig,
@@ -131,7 +130,7 @@ class SimResult:
     # with realized_metrics): p50/p95/p99 latency, violation rate, ...
     ws_latency: Optional[Dict[str, float]] = None
     # N-department accounting: one TenantResult per registered department
-    # (the legacy scalar fields above are the batch/latency aggregates)
+    # (the scalar fields above are the batch/latency aggregates)
     tenants: Dict[str, TenantResult] = field(default_factory=dict)
     policy: str = "paper"
     # engine state snapshot: reclaim plans made, per-victim drain counts,
@@ -176,22 +175,17 @@ class _TenantRuntime:
 
 
 class ConsolidationSim:
-    def __init__(self, cfg: SimConfig, jobs: Optional[List[Job]] = None,
-                 ws_demand=None, horizon: float = 0.0, *,
-                 tenants: Optional[Sequence[TenantSpec]] = None,
-                 policy=None, tracer: Optional[Tracer] = None,
+    def __init__(self, cfg: SimConfig, *, tenants: Sequence[TenantSpec],
+                 horizon: float, policy="paper",
+                 tracer: Optional[Tracer] = None,
                  defer_queue: bool = False):
-        """Two calling conventions:
-
-        * legacy / paper (degenerate 2-department): ``ConsolidationSim(cfg,
-          jobs, ws_demand, horizon)``. ws_demand: [(t, n), ...] node-demand
-          events OR a ``WSDemandProvider`` (e.g. ``workloads.
-          RequestWorkload``), in which case demand comes from its SLO
-          autoscaler and request-level latency metrics are attached.
-        * N-department: ``ConsolidationSim(cfg, horizon=..., tenants=[...],
-          policy="paper"|"demand_capped"|"proportional_share"|instance)``.
-          Each batch spec carries a job trace; each latency spec a demand
-          timeseries or provider.
+        """``tenants``: one ``TenantSpec`` per department, registered in
+        order. Each batch spec carries a job trace; each latency spec a
+        [(t, n), ...] node-demand timeseries OR a ``WSDemandProvider``
+        (e.g. ``workloads.RequestWorkload``), in which case demand comes
+        from its SLO autoscaler and request-level latency metrics are
+        attached. ``policy``: an engine name from core/policies.py, or an
+        engine instance.
 
         ``defer_queue=True`` skips the per-tenant request-queue simulation
         in the results: each would-be ``realized_metrics`` call is recorded
@@ -213,37 +207,11 @@ class ConsolidationSim:
         self._seq = 0
         self._job_epoch: Dict[Tuple[str, int], int] = {}
 
-        self._degenerate = tenants is None
-        if self._degenerate:
-            # the paper's fixed wiring; registration order (st, ws) is part
-            # of the reproducibility contract (failure attribution order,
-            # timeline columns)
-            tenants = [
-                TenantSpec("st", "batch", priority=1,
-                           jobs=list(jobs) if jobs is not None else []),
-                TenantSpec("ws", "latency", priority=0,
-                           demand=[] if ws_demand is None else ws_demand),
-            ]
-            assert policy is None or str(getattr(
-                policy, "name", policy)) == "paper", \
-                "the legacy 2-tenant call runs the paper policy; pass " \
-                "tenants=[...] to choose another"
-            policy = "paper"
-        else:
-            assert jobs is None and ws_demand is None, \
-                "pass demand sources inside TenantSpec when using tenants=[]"
-            policy = policy if policy is not None else "paper"
         names = [s.name for s in tenants]
         assert len(set(names)) == len(names), f"duplicate tenants: {names}"
 
-        if self._degenerate:
-            self.svc: TenantProvisionService = \
-                ResourceProvisionService(cfg.total_nodes,
-                                         tracer=self.tracer)
-        else:
-            self.svc = TenantProvisionService(cfg.total_nodes, policy=policy,
-                                              tracer=self.tracer)
-        self.rps = self.svc            # legacy attribute name
+        self.svc = TenantProvisionService(cfg.total_nodes, policy=policy,
+                                          tracer=self.tracer)
         self.policy_name = self.svc.policy.name
         self._demand_driven = self.svc.policy.demand_driven
 
@@ -309,18 +277,8 @@ class ConsolidationSim:
                 on_grant = (lambda n, s=rt.server: s.grant(n, self.now))
                 on_force = (lambda n, s=rt.server:
                             s.force_release(n, self.now))
-            if spec.name in self.svc.tenants:   # degenerate: pre-registered
-                rt.record = self.svc.tenants[spec.name]
-                rt.record.on_grant = on_grant
-                rt.record.on_force_release = on_force
-                rt.record.weight = spec.weight
-                rt.record.floor = spec.floor
-                rt.record.bid_weight = spec.bid_weight
-                rt.record.budget = spec.budget
-                rt.record.bid_policy = spec.bid_policy
-            else:
-                rt.record = self.svc.register_spec(
-                    spec, on_grant=on_grant, on_force_release=on_force)
+            rt.record = self.svc.register_spec(
+                spec, on_grant=on_grant, on_force_release=on_force)
             # live CMS signals feed the phase-1 reclaim planner
             rt.record.signals = (
                 lambda rt=rt: rt.server.signals(
@@ -339,13 +297,7 @@ class ConsolidationSim:
              rt.is_batch and hasattr(rt.server, "queue"))
             for rt in self._runtimes]
         self._trace_market = getattr(self.svc.policy, "market", None)
-        # legacy aliases (the paper wiring); first of each class otherwise
-        self.st = self._batch[0].server if self._batch else None
-        self.ws = self._latency[0].server if self._latency else None
         self.jobs: List[Job] = [j for rt in self._batch for j in rt.jobs]
-        self.ws_demand = self._latency[0].demand if self._latency else []
-        self.ws_provider = self._latency[0].provider if self._latency \
-            else None
 
         # timeline accounting
         self._last_t = 0.0

@@ -296,6 +296,17 @@ class TenantSpec:
         assert self.bid_policy in ("linear", "slo_elastic"), self.bid_policy
 
 
+def paper_tenants(jobs=(), ws_demand=()) -> List[TenantSpec]:
+    """The paper's two departments (§II-B) as N-department specs: the ST
+    batch department (``jobs``) and the WS latency department
+    (``ws_demand``: [(t, n), ...] or a ``WSDemandProvider``), run under the
+    ``"paper"`` policy. Registration order (st, ws) is part of the
+    reproducibility contract: node-failure attribution and timeline
+    columns follow it."""
+    return [TenantSpec("st", "batch", priority=1, jobs=list(jobs)),
+            TenantSpec("ws", "latency", priority=0, demand=ws_demand)]
+
+
 class EventKind(enum.Enum):
     JOB_SUBMIT = 1
     JOB_FINISH = 2

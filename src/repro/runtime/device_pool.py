@@ -4,8 +4,8 @@ The provision service reasons in fungible node counts; this pool assigns
 actual devices to named tenant groups: batch tenants (elastic trainers)
 receive rectangular groups (multiples of the training job's model-parallel
 width) so TP collectives stay intact; latency tenants (serving pools)
-receive single devices per replica. The legacy two-group (``st``/``ws``)
-interface is preserved as aliases over the named groups.
+receive single devices per replica. A pool starts with no groups;
+``add_group`` makes one per registered department.
 """
 from __future__ import annotations
 
@@ -15,11 +15,10 @@ import jax
 
 
 class DevicePool:
-    def __init__(self, devices: Optional[Sequence] = None,
-                 groups: Sequence[str] = ("st", "ws")):
+    def __init__(self, devices: Optional[Sequence] = None):
         self.devices = list(devices if devices is not None else jax.devices())
         self.free = list(self.devices)
-        self.groups: Dict[str, List] = {g: [] for g in groups}
+        self.groups: Dict[str, List] = {}
 
     @property
     def total(self) -> int:
@@ -55,23 +54,3 @@ class DevicePool:
         self.check()
         return got
 
-    # ------------------------------------------------- legacy two-tenant API
-    @property
-    def st(self) -> List:
-        return self.groups["st"]
-
-    @property
-    def ws(self) -> List:
-        return self.groups["ws"]
-
-    def grant_st(self, n: int) -> List:
-        return self.grant("st", n)
-
-    def grant_ws(self, n: int) -> List:
-        return self.grant("ws", n)
-
-    def reclaim_st(self, n: int) -> List:
-        return self.reclaim("st", n)
-
-    def release_ws(self, n: int) -> List:
-        return self.reclaim("ws", n)

@@ -12,11 +12,16 @@ device pool:
   trainers per the cooperative policy, growing them at the next step
   boundary.
 
-``PhoenixOrchestrator`` is the paper's two-department wiring (one trainer +
-one serving pool over ``ResourceProvisionService``); ``MultiTenant
-Orchestrator`` runs any department mix over ``TenantProvisionService`` with
-a pluggable cooperative policy — the runtime twin of the N-department
-``ConsolidationSim``.
+``MultiTenantOrchestrator`` runs any department mix over
+``TenantProvisionService`` with a pluggable cooperative policy — the
+runtime twin of the N-department ``ConsolidationSim``. The paper's two
+departments (one trainer + one serving pool) are the degenerate case, wired
+as in :func:`~repro.core.types.paper_tenants`::
+
+    orch = MultiTenantOrchestrator(policy="paper")
+    orch.add_batch("st", trainer, priority=1, min_devices=2)
+    orch.add_latency("ws", pool, priority=0)
+    orch.start()
 """
 from __future__ import annotations
 
@@ -25,8 +30,7 @@ from typing import Dict, List, Optional
 
 from repro.core.cms import proxy_headroom_s
 from repro.core.nodes import NodeInventory
-from repro.core.provision import (ResourceProvisionService,
-                                  TenantProvisionService)
+from repro.core.provision import TenantProvisionService
 from repro.core.telemetry import NULL_TRACER, Tracer
 from repro.core.types import TenantSignals, TenantSpec
 from repro.runtime.device_pool import DevicePool
@@ -74,7 +78,7 @@ class MultiTenantOrchestrator:
     def __init__(self, *, devices=None, policy="paper",
                  tracer: Optional[Tracer] = None, rack_size: int = 16):
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.devs = DevicePool(devices, groups=())
+        self.devs = DevicePool(devices)
         self.svc = TenantProvisionService(self.devs.total, policy=policy,
                                           tracer=self.tracer)
         # identified-node layer: the orchestrator always carries the
@@ -332,100 +336,3 @@ class MultiTenantOrchestrator:
         self.events.append({"kind": "node_repair", "node": node_id})
         return node_id
 
-
-class PhoenixOrchestrator:
-    """The paper's two-department wiring: one ST trainer + one WS pool."""
-
-    def __init__(self, trainer: ElasticTrainer, pool: ServingPool, *,
-                 devices=None, min_st_devices: int = 0,
-                 slo_autoscaler=None):
-        """slo_autoscaler: optional ``workloads.SLOAutoscaler``. When set,
-        ``ws_tick_slo`` scales replicas from request-level load statistics
-        against the latency SLO instead of the §III-C utilization rule."""
-        self.devs = DevicePool(devices)
-        self.rps = ResourceProvisionService(self.devs.total)
-        self.trainer = trainer
-        self.pool = pool
-        self.min_st = max(min_st_devices, trainer.model_size)
-        self.slo_autoscaler = slo_autoscaler
-        self.rps.force_st_release = self._force_st_release
-        self.rps.on_grant_st = self._grant_st
-        self.events: List[Dict] = []
-        self._started = False
-
-    # ------------------------------------------------------------- wiring
-    def _grant_st(self, n: int):
-        self.devs.grant_st(n)
-        if self._started:
-            self._resize_trainer()
-        else:
-            self.trainer.start(self.devs.st)
-            self._started = True
-
-    def _force_st_release(self, n: int) -> int:
-        """Shrink the trainer by n devices, rounded UP to a whole DP group
-        (TP width is preserved) — surplus stays idle and is re-granted."""
-        tp = self.trainer.model_size
-        groups = math.ceil(n / tp)
-        max_groups = (len(self.devs.st) - self.min_st) // tp
-        groups = min(groups, max_groups)
-        take = groups * tp
-        if take <= 0:
-            return 0
-        self.devs.reclaim_st(take)
-        self._resize_trainer()
-        self.events.append({"kind": "st_shrink", "devices": take,
-                            "step": self.trainer.step})
-        return take
-
-    def _resize_trainer(self):
-        if self._started and self.devs.st:
-            self.trainer.resize(self.devs.st)
-
-    # ------------------------------------------------------------- control
-    def start(self):
-        self.rps.provision_idle_to_st()
-
-    def ws_tick(self, offered_load_tokens: float):
-        """One WS control interval: autoscale replicas to the offered load
-        (paper §III-C utilization rule)."""
-        self._scale_ws(self.pool.desired_replicas(offered_load_tokens))
-
-    def ws_tick_slo(self, rate_rps: float, mean_service_s: float,
-                    scv_service: float = 1.0,
-                    p99_service_s: Optional[float] = None):
-        """One WS control interval driven by the latency SLO.
-
-        Takes the window's request-level load statistics (arrival rate and
-        service-time shape, e.g. from ``ServiceTimeModel.service_times`` over
-        the window's token counts) and asks the SLO autoscaler for the
-        replica count whose predicted latency percentile meets the target.
-        """
-        assert self.slo_autoscaler is not None, \
-            "construct PhoenixOrchestrator(..., slo_autoscaler=...) first"
-        if p99_service_s is None:
-            # gamma-tail estimate from the SCV; using the mean here would
-            # make the predicted percentile systematically optimistic
-            p99_service_s = mean_service_s * (
-                1.0 + 2.33 * math.sqrt(max(scv_service, 0.0)))
-        want = self.slo_autoscaler.desired_nodes(
-            rate_rps, mean_service_s, scv_service, p99_service_s,
-            current=len(self.pool.replicas))
-        self._scale_ws(want)
-
-    def _scale_ws(self, want: int):
-        have = len(self.pool.replicas)
-        if want > have:
-            got = self.rps.ws_request(want - have)
-            self.devs.grant_ws(got)
-        elif want < have:
-            give = have - want
-            self.devs.release_ws(give)
-            self.pool.scale_to(self.devs.ws)   # before the devices move on
-            self.rps.ws_release(give)
-        self.pool.scale_to(self.devs.ws)
-        self.events.append({"kind": "ws_scale", "replicas":
-                            len(self.pool.replicas)})
-
-    def train_steps(self, n: int) -> Dict:
-        return self.trainer.train_steps(n)

@@ -9,9 +9,8 @@ G/G/k wait + exponential tail) meets the SLO, with square-root-staffing
 headroom and scale-down hysteresis so the demand curve doesn't flap.
 
 ``RequestWorkload`` packages a trace + model + SLO into the
-``WSDemandProvider`` protocol consumed by ``ConsolidationSim`` and
-``PhoenixOrchestrator``: planned demand events in, realized latency metrics
-out.
+``WSDemandProvider`` protocol consumed by ``ConsolidationSim``: planned
+demand events in, realized latency metrics out.
 """
 from __future__ import annotations
 

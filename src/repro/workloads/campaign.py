@@ -69,7 +69,7 @@ from repro.core.policies import POLICIES
 from repro.core.simulator import ConsolidationSim
 from repro.core.telemetry import Tracer, summarize_events
 from repro.core.traces import synthetic_sdsc_blue
-from repro.core.types import SimConfig, SLOConfig, TenantSpec
+from repro.core.types import SimConfig, SLOConfig, TenantSpec, paper_tenants
 from repro.serving.batching import ServiceTimeModel
 from repro.workloads.arrivals import GENERATORS, make_trace
 from repro.workloads.autoscaler import RequestWorkload
@@ -288,7 +288,19 @@ def shard_cells(cells: Sequence[ScenarioCell],
 def make_tenants(cell: ScenarioCell) -> List[TenantSpec]:
     """Build the department mix for one cell: HPC departments split the job
     trace, WS departments split the request rate, an optional best-effort
-    batch tenant rides at the lowest priority."""
+    batch tenant rides at the lowest priority. ``paper2`` under the
+    ``paper`` policy is the paper's own wiring (``st``/``ws``, the seed
+    pipeline's traces)."""
+    if cell.mix == "paper2" and cell.policy == "paper":
+        return paper_tenants(
+            synthetic_sdsc_blue(seed=cell.seed, n_jobs=cell.n_jobs,
+                                horizon=cell.horizon_s,
+                                max_nodes=cell.st_max_nodes),
+            RequestWorkload(
+                trace=make_trace(cell.arrival, cell.rate_rps,
+                                 cell.horizon_s, cell.seed),
+                model=ServiceTimeModel(),
+                slo=SLOConfig(latency_target_s=cell.slo_target_s)))
     n_hpc, n_ws, n_be = MIXES[cell.mix]
     # market axis (v5): a finite budget makes every department pay for
     # nodes under the budget engines; latency departments then also bid
@@ -364,31 +376,15 @@ def _cell_start(cell: ScenarioCell,
                     preempt_mode=cell.preempt,
                     scheduler=cell.scheduler, seed=cell.seed,
                     faults=get_fault_spec(cell.fault_profile))
-    if cell.mix == "paper2" and cell.policy == "paper":
-        # the degenerate 2-tenant path (bit-identical to the seed pipeline)
-        jobs = synthetic_sdsc_blue(seed=cell.seed, n_jobs=cell.n_jobs,
-                                   horizon=cell.horizon_s,
-                                   max_nodes=cell.st_max_nodes)
-        trace = make_trace(cell.arrival, cell.rate_rps, cell.horizon_s,
-                           cell.seed)
-        workload = RequestWorkload(
-            trace=trace, model=ServiceTimeModel(),
-            slo=SLOConfig(latency_target_s=cell.slo_target_s))
-        sim = ConsolidationSim(cfg, jobs, workload, horizon=cell.horizon_s,
-                               tracer=tracer, defer_queue=defer)
-        ws_requests = len(trace)
-        peak = max((n for _, n in workload.demand_events(cell.horizon_s)),
+    tenants = make_tenants(cell)
+    sim = ConsolidationSim(cfg, horizon=cell.horizon_s, tenants=tenants,
+                           policy=cell.policy, tracer=tracer,
+                           defer_queue=defer)
+    ws_requests = sum(len(s.demand.trace) for s in tenants
+                      if s.kind == "latency")
+    peak = sum(max((n for _, n in s.demand.demand_events(cell.horizon_s)),
                    default=0)
-    else:
-        tenants = make_tenants(cell)
-        sim = ConsolidationSim(cfg, horizon=cell.horizon_s, tenants=tenants,
-                               policy=cell.policy, tracer=tracer,
-                               defer_queue=defer)
-        ws_requests = sum(len(s.demand.trace) for s in tenants
-                          if s.kind == "latency")
-        peak = sum(max((n for _, n in s.demand.demand_events(cell.horizon_s)),
-                       default=0)
-                   for s in tenants if s.kind == "latency")
+               for s in tenants if s.kind == "latency")
     res = sim.run()
 
     names: List[str] = []

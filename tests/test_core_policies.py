@@ -1,9 +1,10 @@
 """Unit tests for the paper's provisioning / CMS policies (§II-B)."""
 import pytest
 
-from repro.core.provision import ResourceProvisionService
+from repro.core.provision import TenantProvisionService
 from repro.core.st_cms import STServer
-from repro.core.types import Job, JobState, SimConfig, SLOConfig
+from repro.core.types import (Job, JobState, SimConfig, SLOConfig,
+                              paper_tenants)
 from repro.core.ws_cms import WSServer, demand_from_load
 
 import numpy as np
@@ -16,39 +17,46 @@ def make_st(cfg=None):
     return st, finishes
 
 
+def paper_service(total, **hooks):
+    """The paper's service: st then ws under the paper policy; ``hooks``
+    are the st department's on_grant/on_force_release."""
+    svc = TenantProvisionService(total, policy="paper")
+    st_spec, ws_spec = paper_tenants()
+    svc.register_spec(st_spec, **hooks)
+    svc.register_spec(ws_spec)
+    return svc, svc.tenants["st"], svc.tenants["ws"]
+
+
 def test_provision_idle_goes_to_st():
-    rps = ResourceProvisionService(100)
     granted = []
-    rps.on_grant_st = granted.append
-    rps.provision_idle_to_st()
-    assert rps.st_alloc == 100 and rps.free == 0 and granted == [100]
+    svc, st, _ = paper_service(100, on_grant=granted.append)
+    svc.provision_idle()
+    assert st.alloc == 100 and svc.free == 0 and granted == [100]
 
 
 def test_ws_priority_forces_st_release():
-    rps = ResourceProvisionService(10)
-    rps.provision_idle_to_st()
     released = []
 
     def force(n):
         released.append(n)
         return n
 
-    rps.force_st_release = force
-    got = rps.ws_request(4)
+    svc, st, ws = paper_service(10, on_force_release=force)
+    svc.provision_idle()
+    got = svc.claim("ws", 4)
     assert got == 4 and released == [4]
-    assert rps.ws_alloc == 4 and rps.st_alloc == 6
-    rps.check()
+    assert ws.alloc == 4 and st.alloc == 6
+    svc.check()
 
 
 def test_ws_release_reprovisions_to_st():
-    rps = ResourceProvisionService(10)
-    rps.force_st_release = lambda n: n
-    rps.provision_idle_to_st()
-    rps.ws_request(5)
-    assert rps.ws_alloc == 5
-    rps.ws_release(3)
+    svc, st, ws = paper_service(10, on_force_release=lambda n: n)
+    svc.provision_idle()
+    svc.claim("ws", 5)
+    assert ws.alloc == 5
+    svc.release("ws", 3)
     # released nodes must flow straight back to ST (rule 2)
-    assert rps.free == 0 and rps.st_alloc == 8 and rps.ws_alloc == 2
+    assert svc.free == 0 and st.alloc == 8 and ws.alloc == 2
 
 
 def test_kill_order_min_size_then_shortest_running():

@@ -12,7 +12,7 @@ from repro.core.simulator import ConsolidationSim
 from repro.core.telemetry import (Tracer, check_causal_chains,
                                   summarize_events, validate_events)
 from repro.core.traces import synthetic_sdsc_blue
-from repro.core.types import SimConfig, TenantSpec
+from repro.core.types import SimConfig, TenantSpec, paper_tenants
 
 DAY = 86400.0
 HORIZON = 7200.0
@@ -58,7 +58,8 @@ def test_independent_profile_reproduces_legacy_mtbf_bit_for_bit():
         jobs = synthetic_sdsc_blue(seed=3, n_jobs=120, horizon=2 * DAY,
                                    max_nodes=64)
         dem = [(t * 600.0, 20 + (t % 7) * 5) for t in range(200)]
-        return ConsolidationSim(cfg, jobs, dem, horizon=2 * DAY).run()
+        return ConsolidationSim(cfg, tenants=paper_tenants(jobs, dem),
+                                horizon=2 * DAY).run()
 
     legacy = run(SimConfig(total_nodes=160, node_mtbf=50 * DAY,
                            node_repair_time=3600.0, seed=3))
@@ -167,8 +168,8 @@ def test_suppressed_faults_traced_and_repairs_never_overshoot():
                                      repair_time_s=50_000.0))
     jobs = synthetic_sdsc_blue(seed=1, n_jobs=5, horizon=HORIZON,
                                max_nodes=2)
-    sim = ConsolidationSim(cfg, jobs, [(0.0, 1)], horizon=HORIZON,
-                           tracer=tr)
+    sim = ConsolidationSim(cfg, tenants=paper_tenants(jobs, [(0.0, 1)]),
+                           horizon=HORIZON, tracer=tr)
     sim.run()
     s = summarize_events([tr.header()] + tr.events)["faults"]
     assert s["suppressed"] > 0
@@ -260,7 +261,8 @@ def test_sim_level_drain_time_slows_ws_recovery():
         dem = [(t * 600.0, 10 + (t % 3) * 15) for t in range(12)]
         cfg = SimConfig(total_nodes=64, seed=2, drain_time_s=drain_s,
                         faults=FaultSpec(profile="independent", mtbf_s=0.0))
-        return ConsolidationSim(cfg, jobs, dem, horizon=HORIZON).run()
+        return ConsolidationSim(cfg, tenants=paper_tenants(jobs, dem),
+                                horizon=HORIZON).run()
 
     instant = run(0.0)
     drained = run(120.0)

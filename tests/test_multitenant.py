@@ -7,11 +7,15 @@ try:
 except ImportError:       # container without hypothesis: property tests skip
     HAS_HYPOTHESIS = False
 
-from repro.core.policies import MultiTenantProvisionService, Tenant
+from repro.core.policies import Tenant
+from repro.core.provision import TenantProvisionService
 
 
-def make_service(total=100, greedy_idle=False):
-    svc = MultiTenantProvisionService(total, greedy_idle=greedy_idle)
+def make_service(total=100, policy="demand_capped"):
+    """Strict priorities; ``"paper"`` dumps ALL leftover idle nodes on the
+    highest-priority batch tenant, ``"demand_capped"`` stops grants at
+    declared demand and leaves the remainder free."""
+    svc = TenantProvisionService(total, policy=policy)
     freed = {"st1": 0, "st2": 0}
 
     def releaser(name):
@@ -30,7 +34,7 @@ def make_service(total=100, greedy_idle=False):
 
 
 def test_idle_flows_to_highest_priority_batch_greedy():
-    svc, _ = make_service(greedy_idle=True)
+    svc, _ = make_service(policy="paper")
     svc.tenants["st1"].demand = 30
     svc.tenants["st2"].demand = 50
     svc.provision_idle()
@@ -63,9 +67,9 @@ def test_zero_demand_tenant_gets_nothing_by_default():
 
 
 def test_two_tenant_special_case_matches_paper():
-    """With one WS + one ST and greedy_idle this reduces to the paper's
-    three rules."""
-    svc = MultiTenantProvisionService(10, greedy_idle=True)
+    """With one WS + one ST and the paper policy this reduces to the
+    paper's three rules."""
+    svc = TenantProvisionService(10, policy="paper")
     svc.register(Tenant("ws", "latency", priority=0))
     svc.register(Tenant("st", "batch", priority=1,
                         on_force_release=lambda n: n))
@@ -95,8 +99,8 @@ def test_reclaim_drains_all_batch_before_latency_tenants():
     """Claim ordering: batch tenants (reverse priority) are fully drained
     before any lower-priority latency tenant is touched."""
     svc, freed = make_service()
-    svc.set_batch_demand("st1", 20)
-    svc.set_batch_demand("st2", 20)
+    svc.set_demand("st1", 20)
+    svc.set_demand("st2", 20)
     svc.claim("ws2", 60)               # ws2 takes the free pool
     assert svc.free == 0
     # ws1 needs 50: free(0) -> st2(20) -> st1(20) -> only then ws2(10)
@@ -110,7 +114,7 @@ def test_reclaim_drains_all_batch_before_latency_tenants():
 
 def test_reclaim_spares_latency_when_batch_suffices():
     svc, freed = make_service()
-    svc.set_batch_demand("st1", 30)
+    svc.set_demand("st1", 30)
     svc.claim("ws2", 40)
     got = svc.claim("ws1", 55)          # free 30 + st1's 30 > 55 - no ws2 hit
     assert got == 55
@@ -142,14 +146,14 @@ if not HAS_HYPOTHESIS:
         pass
 else:
     @given(total=st.integers(10, 200),
-           greedy=st.booleans(),
+           policy=st.sampled_from(["paper", "demand_capped"]),
            ops=st.lists(st.tuples(st.sampled_from(["claim1", "claim2",
                                                    "rel1", "rel2",
                                                    "demand1", "demand2"]),
                                   st.integers(0, 80)), max_size=40))
     @settings(max_examples=80, deadline=None)
-    def test_conservation_under_arbitrary_ops(total, greedy, ops):
-        svc, _ = make_service(total, greedy_idle=greedy)
+    def test_conservation_under_arbitrary_ops(total, policy, ops):
+        svc, _ = make_service(total, policy=policy)
         for op, n in ops:
             if op == "claim1":
                 svc.claim("ws1", n)
@@ -160,8 +164,8 @@ else:
             elif op == "rel2":
                 svc.release("ws2", n)
             elif op == "demand1":
-                svc.set_batch_demand("st1", n)
+                svc.set_demand("st1", n)
             else:
-                svc.set_batch_demand("st2", n)
+                svc.set_demand("st2", n)
             svc.check()
             assert svc.free >= 0

@@ -1,7 +1,7 @@
 """Property-based tests (hypothesis) for the Phoenix Cloud invariants.
 
 System invariants under arbitrary job sets and WS demand curves:
-  * node conservation: free + st_alloc + ws_alloc == total, always;
+  * node conservation: free + st alloc + ws alloc == total, always;
   * WS priority: unmet demand only when demand exceeds total capacity;
   * ST never runs more nodes than allocated;
   * completed jobs have turnaround >= runtime;
@@ -17,7 +17,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core.policies import POLICIES
 from repro.core.simulator import ConsolidationSim
-from repro.core.types import Job, JobState, SimConfig
+from repro.core.types import Job, JobState, SimConfig, paper_tenants
 
 HOUR = 3600.0
 HORIZON = 48 * HOUR
@@ -52,13 +52,15 @@ class AuditedSim(ConsolidationSim):
         # monkeypatch accounting hook to audit at every event boundary
         orig_account = self._account
 
+        st = self._rt_by_name["st"].server
+        ws = self._rt_by_name["ws"].server
+
         def audited(t):
             orig_account(t)
-            self.rps.check()
-            assert self.st.used <= self.st.alloc, \
-                (self.st.used, self.st.alloc)
-            assert self.st.alloc == self.rps.st_alloc
-            assert self.ws.alloc == self.rps.ws_alloc
+            self.svc.check()
+            assert st.used <= st.alloc, (st.used, st.alloc)
+            assert st.alloc == self.svc.tenants["st"].alloc
+            assert ws.alloc == self.svc.tenants["ws"].alloc
 
         self._account = audited
         return super().run()
@@ -70,7 +72,8 @@ class AuditedSim(ConsolidationSim):
 @settings(max_examples=60, deadline=None)
 def test_invariants_hold(jobs, demand, total, mode):
     cfg = SimConfig(total_nodes=total, preempt_mode=mode)
-    sim = AuditedSim(cfg, jobs, demand, horizon=HORIZON)
+    sim = AuditedSim(cfg, tenants=paper_tenants(jobs, demand),
+                     horizon=HORIZON)
     res = sim.run()
 
     # WS priority: unmet only when demand > total
@@ -177,19 +180,22 @@ def test_any_engine_conserves_and_respects_floors_under_faults(
                                                 min_size=1, max_size=30))
 @settings(max_examples=60, deadline=None)
 def test_provision_service_conservation(total, req):
-    from repro.core.provision import ResourceProvisionService
-    rps = ResourceProvisionService(total)
-    rps.force_st_release = lambda n: min(n, rps.st_alloc)
-    rps.provision_idle_to_st()
+    from repro.core.provision import TenantProvisionService
+    svc = TenantProvisionService(total, policy="paper")
+    for spec in paper_tenants():
+        svc.register_spec(spec)
+    st_rec, ws_rec = svc.tenants["st"], svc.tenants["ws"]
+    st_rec.on_force_release = lambda n: min(n, st_rec.alloc)
+    svc.provision_idle()
     ws_alloc = 0
     for r in req:
         if ws_alloc > 0 and r % 3 == 0:
             give = min(ws_alloc, r)
-            rps.ws_release(give)
+            svc.release("ws", give)
             ws_alloc -= give
         else:
-            got = rps.ws_request(r)
+            got = svc.claim("ws", r)
             assert got <= r
             ws_alloc += got
-        rps.check()
-        assert rps.ws_alloc == ws_alloc
+        svc.check()
+        assert ws_rec.alloc == ws_alloc

@@ -97,7 +97,7 @@ def test_orchestrator_policy_shrinks_and_grows_trainer(tmp_path):
         from repro.configs.base import TrainConfig
         from repro.runtime.elastic import ElasticTrainer
         from repro.runtime.serving_pool import ServingPool
-        from repro.runtime.orchestrator import PhoenixOrchestrator
+        from repro.runtime.orchestrator import MultiTenantOrchestrator
         from repro.models import model as M
 
         cfg = reduced_config(ARCHS["deepseek-7b"])
@@ -106,20 +106,22 @@ def test_orchestrator_policy_shrinks_and_grows_trainer(tmp_path):
                                  model_size=1)
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         pool = ServingPool(cfg, params, capacity_tokens_per_replica=100.0)
-        orch = PhoenixOrchestrator(trainer, pool, min_st_devices=2)
+        orch = MultiTenantOrchestrator(policy="paper")
+        orch.add_batch("st", trainer, priority=1, min_devices=2)
+        orch.add_latency("ws", pool, priority=0)
         orch.start()                       # all 8 devices -> trainer
-        assert len(orch.devs.st) == 8
-        orch.train_steps(1)
-        orch.ws_tick(offered_load_tokens=90.0)   # util>0.8 -> scale up
+        assert len(orch.devs.groups["st"]) == 8
+        orch.train_steps("st", 1)
+        orch.latency_tick("ws", 90.0)            # util>0.8 -> scale up
         assert len(pool.replicas) == 2
-        assert len(orch.devs.st) == 6            # trainer shrank
-        orch.train_steps(1)
+        assert len(orch.devs.groups["st"]) == 6  # trainer shrank
+        orch.train_steps("st", 1)
         # serve a request through the balancer
         outp = pool.submit(np.array([[1,2,3,4]], dtype=np.int32), 4)
         assert outp.shape == (1, 4)
-        orch.ws_tick(offered_load_tokens=0.0)    # scale down
+        orch.latency_tick("ws", 0.0)             # scale down
         assert len(pool.replicas) == 1           # floor n=1
-        m = orch.train_steps(1)
+        m = orch.train_steps("st", 1)
         assert np.isfinite(m["loss"])
         print("EVENTS", orch.events)
         print("OK")

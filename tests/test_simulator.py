@@ -8,7 +8,7 @@ from repro.core.experiment import (DC_SIZES, SC_TOTAL, run_dynamic,
 from repro.core.simulator import ConsolidationSim
 from repro.core.traces import (TWO_WEEKS_S, synthetic_sdsc_blue,
                                worldcup_demand_events)
-from repro.core.types import Job, JobState, SimConfig
+from repro.core.types import Job, JobState, SimConfig, paper_tenants
 
 DAY = 86400.0
 
@@ -38,7 +38,8 @@ def test_ws_demand_always_met_when_capacity_suffices(small_world):
 def test_turnaround_at_least_runtime(small_world):
     jobs, ws = small_world
     cfg = SimConfig(total_nodes=160)
-    sim = ConsolidationSim(cfg, jobs, ws, horizon=2 * DAY)
+    sim = ConsolidationSim(cfg, tenants=paper_tenants(jobs, ws),
+                           horizon=2 * DAY)
     sim.run()
     for j in sim.jobs:
         if j.state is JobState.COMPLETED:

@@ -29,7 +29,7 @@ from repro.core.telemetry import (NULL_TRACER, Tracer, causality_report,
                                   to_perfetto, validate_events)
 from repro.core.traces import synthetic_sdsc_blue
 from repro.core.types import (MarketState, Job, SimConfig, SLOConfig,
-                              TenantSpec)
+                              TenantSpec, paper_tenants)
 from repro.serving.batching import ServiceTimeModel
 from repro.workloads.arrivals import make_trace
 from repro.workloads.autoscaler import RequestWorkload
@@ -45,7 +45,8 @@ def paper_two_tenant_trace(metric_interval_s=600.0):
             Job(job_id=1, submit_time=300.0, size=4, runtime=900.0)]
     ws = [(0.0, 2), (600.0, 8), (1200.0, 3)]
     tr = Tracer(metric_interval_s=metric_interval_s)
-    sim = ConsolidationSim(SimConfig(total_nodes=10, seed=0), jobs, ws,
+    sim = ConsolidationSim(SimConfig(total_nodes=10, seed=0),
+                           tenants=paper_tenants(jobs, ws),
                            horizon=1800.0, tracer=tr)
     sim.run()
     return tr
@@ -250,7 +251,8 @@ def test_null_tracer_is_disabled_and_shared():
     assert NULL_TRACER.enabled is False
     NULL_TRACER.emit("claim", tenant="x")       # must be a no-op
     assert NULL_TRACER.events == []
-    sim_tr = ConsolidationSim(SimConfig(total_nodes=4, seed=0), [], [],
+    sim_tr = ConsolidationSim(SimConfig(total_nodes=4, seed=0),
+                              tenants=paper_tenants(),
                               horizon=10.0).tracer
     assert sim_tr is NULL_TRACER
 

@@ -24,11 +24,11 @@ from repro.core.policies import (AuctionEngine, DemandCappedIdlePolicy,
                                  PaperPolicy, POLICIES,
                                  ProportionalSharePolicy, SLOHeadroomEngine,
                                  Tenant, get_policy)
-from repro.core.provision import (ResourceProvisionService,
-                                  TenantProvisionService)
+from repro.core.provision import TenantProvisionService
 from repro.core.simulator import (ConsolidationSim, downsample_timeline)
 from repro.core.traces import synthetic_sdsc_blue, worldcup_demand_events
-from repro.core.types import SimConfig, TenantSignals, TenantSpec
+from repro.core.types import (SimConfig, TenantSignals, TenantSpec,
+                              paper_tenants)
 
 DAY = 86400.0
 
@@ -465,11 +465,14 @@ def test_node_failed_empty_pool_reattributes_not_desyncs():
 
 
 def test_legacy_facade_node_failed_empty_pool():
-    rps = ResourceProvisionService(4)
-    rps.provision_idle_to_st()
-    rps.node_failed("ws")          # ws owns nothing -> reattributed to st
-    assert rps.total == 3 and rps.st_alloc == 3 and rps.ws_alloc == 0
-    rps.check()
+    svc = TenantProvisionService(4, policy="paper")
+    for spec in paper_tenants():
+        svc.register_spec(spec)
+    svc.provision_idle()
+    svc.node_failed("ws")          # ws owns nothing -> reattributed to st
+    assert svc.total == 3 and svc.tenants["st"].alloc == 3
+    assert svc.tenants["ws"].alloc == 0
+    svc.check()
 
 
 # ------------------------------------------------------ timeline downsample
@@ -594,10 +597,9 @@ class _ExclusiveTrainer(_StubTrainer):
         super().resize(devices)
 
 
-@pytest.mark.parametrize("wiring", ["multitenant", "phoenix"])
+@pytest.mark.parametrize("wiring", ["multitenant", "paper"])
 def test_released_replicas_leave_before_the_trainer_arrives(wiring):
-    from repro.runtime.orchestrator import (MultiTenantOrchestrator,
-                                            PhoenixOrchestrator)
+    from repro.runtime.orchestrator import MultiTenantOrchestrator
     devices = [f"dev{i}" for i in range(4)]
     pool = _StubPool()
     tr = _ExclusiveTrainer([pool], model_size=1, global_batch=12)
@@ -609,10 +611,12 @@ def test_released_replicas_leave_before_the_trainer_arrives(wiring):
         orch.start()
         tick = lambda n: orch.latency_tick("serve", float(n))  # noqa: E731
     else:
-        orch = PhoenixOrchestrator(tr, pool, devices=devices,
-                                   min_st_devices=2)
+        # the paper's wiring: st (the trainer), then ws (the pool)
+        orch = MultiTenantOrchestrator(devices=devices, policy="paper")
+        orch.add_batch("st", tr, priority=1, min_devices=2)
+        orch.add_latency("ws", pool, priority=0)
         orch.start()
-        tick = lambda n: orch.ws_tick(float(n))  # noqa: E731
+        tick = lambda n: orch.latency_tick("ws", float(n))  # noqa: E731
     for want in (2, 1, 2, 0):
         tick(want)
         assert len(pool.replicas) == want

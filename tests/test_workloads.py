@@ -5,7 +5,8 @@ import pytest
 
 from repro.core.simulator import ConsolidationSim
 from repro.core.traces import synthetic_sdsc_blue
-from repro.core.types import Request, SimConfig, SLOConfig, WSDemandProvider
+from repro.core.types import (Request, SimConfig, SLOConfig, WSDemandProvider,
+                              paper_tenants)
 from repro.serving.batching import ContinuousBatcher, ServiceTimeModel
 from repro.serving.batching import Request as BatchRequest
 from repro.workloads import (RequestWorkload, SLOAutoscaler, burstiness_index,
@@ -166,7 +167,8 @@ def test_consolidation_sim_with_request_workload():
     jobs = synthetic_sdsc_blue(seed=0, n_jobs=60, horizon=2 * HOUR,
                                max_nodes=32)
     cfg = SimConfig(total_nodes=64)
-    res = ConsolidationSim(cfg, jobs, ws, horizon=2 * HOUR).run()
+    res = ConsolidationSim(cfg, tenants=paper_tenants(jobs, ws),
+                           horizon=2 * HOUR).run()
     assert res.ws_latency is not None
     assert res.ws_latency["n_requests"] == len(tr)
     # WS has strict priority and the cluster is big enough: SLO holds
@@ -182,31 +184,36 @@ def test_consolidation_sim_request_workload_deterministic():
     outs = []
     for _ in range(2):
         ws = RequestWorkload(trace=tr, model=MODEL, slo=SLO)
-        res = ConsolidationSim(SimConfig(total_nodes=48), jobs, ws,
+        res = ConsolidationSim(SimConfig(total_nodes=48),
+                               tenants=paper_tenants(jobs, ws),
                                horizon=HOUR).run()
         outs.append((res.completed, res.ws_latency["p99_s"]))
     assert outs[0] == outs[1]
 
 
 def test_node_fail_accounting_stays_consistent():
-    """Satellite fix: ST node loss routes through STServer, so st.alloc and
-    rps.st_alloc can never diverge — audited at every event."""
+    """Satellite fix: ST node loss routes through STServer, so the CMS's
+    alloc and the service's st record can never diverge — audited at
+    every event."""
     tr = make_trace("poisson", 0.5, 2 * HOUR, seed=5)
     ws = RequestWorkload(trace=tr, model=MODEL, slo=SLO)
     jobs = synthetic_sdsc_blue(seed=5, n_jobs=80, horizon=2 * HOUR,
                                max_nodes=32)
     cfg = SimConfig(total_nodes=48, node_mtbf=20 * HOUR,
                     node_repair_time=600.0)
-    sim = ConsolidationSim(cfg, jobs, ws, horizon=2 * HOUR)
+    sim = ConsolidationSim(cfg, tenants=paper_tenants(jobs, ws),
+                           horizon=2 * HOUR)
     orig = sim._account
+    st_cms = sim._rt_by_name["st"].server
+    ws_cms = sim._rt_by_name["ws"].server
+    st_rec, ws_rec = sim.svc.tenants["st"], sim.svc.tenants["ws"]
 
     def audited(t):
         orig(t)
-        sim.rps.check()
-        assert sim.st.alloc == sim.rps.st_alloc, \
-            (sim.st.alloc, sim.rps.st_alloc)
-        assert sim.ws.alloc == sim.rps.ws_alloc
-        assert sim.st.used <= sim.st.alloc
+        sim.svc.check()
+        assert st_cms.alloc == st_rec.alloc, (st_cms.alloc, st_rec.alloc)
+        assert ws_cms.alloc == ws_rec.alloc
+        assert st_cms.used <= st_cms.alloc
 
     sim._account = audited
     res = sim.run()
